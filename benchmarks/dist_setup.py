@@ -75,6 +75,8 @@ def main(argv=None) -> None:
     parser.add_argument("--smoke", action="store_true")
     parser.add_argument("--out", default="BENCH_dist_setup.json")
     args = parser.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     data = rows(smoke=args.smoke)
     print("name,us_per_call,derived")
     for name, us, derived in data:

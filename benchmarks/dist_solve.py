@@ -511,6 +511,8 @@ def main(argv=None) -> None:
     args = parser.parse_args(argv)
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         from benchmarks.serve_load import serving_latency_rows
     except ImportError:

@@ -64,7 +64,7 @@ def rows(smoke: bool | None = None):
 
     from repro.amg.csr import csr_to_bcsr
     from repro.amg.problems import laplace_3d
-    from repro.kernels.spmv.bcsr import bcsr_apply_ref
+    from repro.kernels.spmv.bcsr import bcsr_apply
     from repro.kernels.spmv.ops import select_local_kernel
     from repro.kernels.spmv.ref import ell_spmm_ref, ell_spmv_ref
     from repro.launch.roofline import ert_sweep
@@ -137,7 +137,7 @@ def rows(smoke: bool | None = None):
     bcols = jnp.asarray(B.bcols)
     bvals = jnp.asarray(B.bvals, dtype=jnp.float32)
     bcsr_fn = jax.jit(
-        lambda bc, bv, xx: bcsr_apply_ref(bc, bv, xx)[: nrows])
+        lambda bc, bv, xx: bcsr_apply(bc, bv, xx)[: nrows])
     mb, Kb = B.bcols.shape
     bcsr_bytes = (mb * Kb * 4 + mb * Kb * bs * bs * dsize
                   + mb * Kb * bs * K_RHS * dsize + mb * bs * K_RHS * dsize)
@@ -156,6 +156,8 @@ def main(argv=None) -> None:
     parser.add_argument("--smoke", action="store_true")
     parser.add_argument("--out", default="BENCH_kernels.json")
     args = parser.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     data = rows(smoke=args.smoke)
     print("name,us_per_call,derived")
     for name, us, derived in data:

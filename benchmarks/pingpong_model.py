@@ -79,7 +79,6 @@ def measure_machine_params(name: str = "measured_mesh",
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.compat import shard_map
     from repro.core.perf_model import MachineParams, register_machine
 
     if n_pods is None or lanes is None:
@@ -98,8 +97,8 @@ def measure_machine_params(name: str = "measured_mesh",
                 perm = [(i, (i + 1) % size) for i in range(size)]
                 return jax.lax.ppermute(x[0], axis, perm)[None]
 
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=spec,
-                                   out_specs=spec, check_vma=False))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                       out_specs=spec, check_vma=False))
             x = jnp.zeros((D, n), jnp.float32)
             samples.append((float(nbytes), _time_fn(fn, x, reps=reps)))
         return samples

@@ -6,6 +6,7 @@ import time
 from . import (amg_levels, amg_scaling, comm_strategies, dist_setup,
                dist_solve, kernels, lm_roofline, pingpong_model, ptap_sweeps)
 from repro.core.perf_model import BLUE_WATERS, QUARTZ
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("fig8_9", lambda: pingpong_model.rows()),
@@ -32,6 +33,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived")
     only = sys.argv[1] if len(sys.argv) > 1 else None
     for tag, fn in MODULES:

@@ -360,8 +360,10 @@ def main(argv=None) -> int:
         host, _, port = args.connect.rpartition(":")
         host, port = host or "127.0.0.1", int(port)
     else:
+        from repro.launch.compile_cache import enable_compile_cache
         from repro.serve import ServerThread, TenantSpec
 
+        enable_compile_cache()
         cfg = AMGConfig(backend="host", tol=default_tol("host"))
         srv_cm = ServerThread({name: TenantSpec(config=cfg,
                                                 max_inflight=quota)

@@ -149,7 +149,7 @@ class RequestOptions:
 @dataclasses.dataclass(frozen=True)
 class AMGConfig:
     """Frozen, hashable description of a full solver session: setup knobs,
-    smoother options, iteration defaults, and backend/mesh/strategy/kernel
+    smoother options, iteration defaults, and backend/mesh/strategy
     knobs.  Hashability is what makes it a cache key — two configs that
     compare equal always produce interchangeable solvers."""
 
@@ -171,15 +171,13 @@ class AMGConfig:
     tol: float = 1e-8
     maxiter: int = 100
     pcg_maxiter: int = 200
-    # -- backend + mesh + strategy + kernel knobs
+    # -- backend + mesh + strategy knobs
     backend: str = "host"                # registry name: "host" | "dist" | …
     n_pods: int = 1
     lanes: int = 1
     strategy: str = "auto"               # "auto" | "standard" | "nap2" | "nap3"
     machine: str = "tpu_v5e"             # repro.core.MACHINES name
     dtype: str = "float32"
-    use_kernel: bool | None = None       # None = auto (Pallas ELL on TPU)
-    interpret: bool | None = None        # None = auto (interpret off-TPU)
     reduce_strategy: str = "nap3"        # norms/dots: "nap3" | "flat"
     # halo-exchange/compute overlap in every distributed apply; False keeps
     # the serial fused form (the parity oracle)
@@ -278,9 +276,7 @@ class AMGConfig:
                  "bfloat16": jnp.bfloat16}[self.dtype]
         return dict(n_pods=self.n_pods, lanes=self.lanes,
                     params=MACHINES[self.machine], strategy=self.strategy,
-                    dtype=dtype, use_kernel=self.use_kernel,
-                    interpret=self.interpret,
-                    reduce_strategy=self.reduce_strategy,
+                    dtype=dtype, reduce_strategy=self.reduce_strategy,
                     overlap=self.overlap)
 
 

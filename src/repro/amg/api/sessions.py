@@ -775,8 +775,7 @@ class AMGSolver:
         base = (fp, tuple(sorted(c.setup_kwargs().items())),
                 c.n_pods, c.lanes, c.strategy, c.machine)
         pkey = base + ("dist_partitioned",)
-        skey = base + ("dist_lowered", c.dtype, c.use_kernel, c.interpret,
-                       c.reduce_strategy, c.overlap)
+        skey = base + ("dist_lowered", c.dtype, c.reduce_strategy, c.overlap)
         dh = self.setup_store.get(skey)
         if dh is None:
             cached = self.setup_store.get(pkey)

@@ -6,7 +6,7 @@ All index arrays are int64; values float64.
 
 Also holds the :class:`BCSR` block layout (dense ``bs×bs`` blocks in a
 block-ELL arrangement) and :func:`csr_to_bcsr` — the host-side lowering the
-MXU-blocked Pallas kernel (:mod:`repro.kernels.spmv.bcsr`) consumes.
+block-ELL product (:mod:`repro.kernels.spmv.bcsr`) consumes.
 """
 from __future__ import annotations
 

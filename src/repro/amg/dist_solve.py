@@ -31,8 +31,8 @@ program (recursion unrolled over levels at trace time; W- and F-cycles
 unroll their repeated coarse visits the same way, so a W-cycle is still a
 single fused device program, just with 2^ℓ visits of level ℓ inlined).
 Each matvec runs halo-exchange collectives for its operator's selected
-strategy followed by a local ELL SpMV, optionally through the Pallas
-:func:`~repro.kernels.spmv.spmv.ell_spmv` kernel.  The block smoothers
+strategy followed by the local product
+(:func:`~repro.kernels.spmv.spmv.ell_apply`).  The block smoothers
 (block-Jacobi, hybrid Gauss-Seidel) apply a per-device dense factor —
 block-diagonal inverses / (D+L)⁻¹ of the device's diagonal block, lowered
 alongside the ELL arrays — after the same halo'd residual, so their
@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.compat import shard_map
 from ..core.nap_collectives import (gather_signature, halo_signature,
                                     hier_all_gather, hier_psum,
                                     reduce_signature)
@@ -146,8 +145,8 @@ class DistHierarchy:
     """
 
     def __init__(self, h: Hierarchy | None, n_pods: int, lanes: int,
-                 levels: list[DistLevel], mesh, dtype, use_kernel: bool,
-                 interpret: bool, reduce_strategy: str):
+                 levels: list[DistLevel], mesh, dtype,
+                 reduce_strategy: str):
         # ``h`` is None when the hierarchy was born partitioned
         # (repro.amg.dist_setup): no host Hierarchy ever existed.
         self.h = h
@@ -156,8 +155,6 @@ class DistHierarchy:
         self.levels = levels
         self.mesh = mesh
         self.dtype = dtype
-        self.use_kernel = use_kernel
-        self.interpret = interpret
         self.reduce_strategy = reduce_strategy
         # multi-RHS routing: True traces the ``*_m`` programs directly on
         # [local, k] operands (native SpMM — one pass over each operator's
@@ -189,8 +186,7 @@ class DistHierarchy:
               params: MachineParams = TPU_V5E,
               strategy: str = "auto",
               strategies: tuple[str, ...] = SOLVE_STRATEGIES,
-              dtype=jnp.float32, mesh=None, use_kernel: bool | None = None,
-              interpret: bool | None = None,
+              dtype=jnp.float32, mesh=None,
               reduce_strategy: str = "nap3",
               overlap: bool = True) -> "DistHierarchy":
         """Lower ``h`` onto the mesh, selecting each operator's strategy.
@@ -199,13 +195,12 @@ class DistHierarchy:
         performance models; any explicit strategy name forces it everywhere.
         ``overlap=False`` keeps the serial fused applies (parity oracle).
         """
-        mesh, use_kernel, interpret = cls._resolve_mesh(
-            n_pods, lanes, mesh, use_kernel, interpret)
+        if mesh is None:
+            mesh = jax.make_mesh((n_pods, lanes), DEV_AXES)
         levels = cls._lower_levels(h.levels, n_pods, lanes, params=params,
                                    strategy=strategy, strategies=strategies,
                                    dtype=dtype)
-        self = cls(h, n_pods, lanes, levels, mesh, dtype, use_kernel,
-                   interpret, reduce_strategy)
+        self = cls(h, n_pods, lanes, levels, mesh, dtype, reduce_strategy)
         self.overlap = bool(overlap)
         return self
 
@@ -216,8 +211,6 @@ class DistHierarchy:
                          strategy: str = "auto",
                          strategies: tuple[str, ...] = SOLVE_STRATEGIES,
                          dtype=jnp.float32, mesh=None,
-                         use_kernel: bool | None = None,
-                         interpret: bool | None = None,
                          reduce_strategy: str = "nap3",
                          overlap: bool = True) -> "DistHierarchy":
         """Lower levels that are **already partitioned** (born on the mesh).
@@ -229,30 +222,18 @@ class DistHierarchy:
         ``setup_records`` (per-level SpGEMM strategy selections + measured
         exchange stats) are merged into the selection table.
         """
-        mesh, use_kernel, interpret = cls._resolve_mesh(
-            n_pods, lanes, mesh, use_kernel, interpret)
+        if mesh is None:
+            mesh = jax.make_mesh((n_pods, lanes), DEV_AXES)
         levels = cls._lower_levels(plevels, n_pods, lanes, params=params,
                                    strategy=strategy, strategies=strategies,
                                    dtype=dtype)
         for rec in setup_records or ():
             levels[rec.level].strategies[rec.op] = rec.strategy
             levels[rec.level].modeled[rec.op] = dict(rec.modeled)
-        self = cls(None, n_pods, lanes, levels, mesh, dtype, use_kernel,
-                   interpret, reduce_strategy)
+        self = cls(None, n_pods, lanes, levels, mesh, dtype, reduce_strategy)
         self.overlap = bool(overlap)
         self.setup_records = list(setup_records or ())
         return self
-
-    @staticmethod
-    def _resolve_mesh(n_pods, lanes, mesh, use_kernel, interpret):
-        on_tpu = jax.default_backend() == "tpu"
-        if use_kernel is None:
-            use_kernel = on_tpu
-        if interpret is None:
-            interpret = not on_tpu
-        if mesh is None:
-            mesh = jax.make_mesh((n_pods, lanes), DEV_AXES)
-        return mesh, use_kernel, interpret
 
     @classmethod
     def _lower_levels(cls, src_levels, n_pods: int, lanes: int, *, params,
@@ -520,8 +501,7 @@ class DistHierarchy:
         return a
 
     def _spmv(self, op: DistOperator, arrs: dict, x):
-        return op.apply(arrs, x, use_kernel=self.use_kernel,
-                        interpret=self.interpret, overlap=self.overlap)
+        return op.apply(arrs, x, overlap=self.overlap)
 
     def _pdot(self, a, b):
         part = jnp.sum(a * b)
@@ -674,8 +654,8 @@ class DistHierarchy:
             return jax.tree_util.tree_map(lambda v: v[0], t)
 
         def smap(f, in_specs, out_specs):
-            return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, check_vma=False))
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                         out_specs=out_specs, check_vma=False))
 
         def spmv0(arrs, x):
             return self._spmv(self.levels[0].A, arrs[0]["A"], x)
@@ -820,11 +800,10 @@ class DistHierarchy:
         def body(x, a):
             x = x[0]
             a = jax.tree_util.tree_map(lambda v: v[0], a)
-            return dop.apply(a, x, use_kernel=self.use_kernel,
-                             interpret=self.interpret, overlap=overlap)[None]
+            return dop.apply(a, x, overlap=overlap)[None]
 
-        fn = shard_map(body, mesh=self.mesh, in_specs=(dev, dev),
-                       out_specs=dev, check_vma=False)
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=(dev, dev),
+                           out_specs=dev, check_vma=False)
         D = self.n_pods * self.lanes
         shape = (D, dop.plan.local_n) + (() if k is None else (k,))
         return jax.make_jaxpr(fn)(jnp.zeros(shape, self.dtype), arrs)
@@ -907,8 +886,7 @@ class DistHierarchy:
 # dicts that spell a default explicitly hit the same entry
 _BUILD_DEFAULTS = dict(params=TPU_V5E, strategy="auto",
                        strategies=SOLVE_STRATEGIES, dtype=jnp.float32,
-                       mesh=None, use_kernel=None, interpret=None,
-                       reduce_strategy="nap3", overlap=True)
+                       mesh=None, reduce_strategy="nap3", overlap=True)
 DIST_CACHE_SIZE = 8
 
 

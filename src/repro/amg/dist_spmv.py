@@ -17,8 +17,9 @@ of host matvecs — each level of the AMG hierarchy gets one
 strategy (see :mod:`repro.amg.dist_solve`).
 
 Execute (device, every smoother sweep / residual / restrict / interpolate):
-  shard_map body = halo_exchange → ELL SpMV (inline jnp gather form, or the
-  Pallas :func:`repro.kernels.spmv.spmv.ell_spmv` kernel).
+  shard_map body = halo_exchange → local product
+  (:func:`repro.kernels.spmv.spmv.ell_apply`, or
+  :func:`repro.kernels.spmv.bcsr.bcsr_apply` on a BCSR-lowered level).
 """
 from __future__ import annotations
 
@@ -29,10 +30,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.comm_graph import CommGraph
-from ..core.compat import shard_map
 from ..core.nap_collectives import (HaloPlan, build_halo_plan, halo_exchange,
                                     halo_signature)
 from ..core.topology import Partition, Topology
+from ..kernels.spmv.bcsr import bcsr_apply
+from ..kernels.spmv.spmv import ell_apply
 from .csr import CSR
 from .dist import rect_vector_graph
 
@@ -114,8 +116,8 @@ class DistOperator:
     on_vals: np.ndarray | None = None
     off_cols: np.ndarray | None = None   # [D, rows_local, K_off] into halo
     off_vals: np.ndarray | None = None
-    # optional BCSR lowering (see lower_bcsr): dense bs×bs blocks feeding the
-    # MXU block-contraction kernel instead of the VPU gather
+    # optional BCSR lowering (see lower_bcsr): dense bs×bs blocks contracted
+    # per block slot instead of one gather per nonzero
     bcsr_bcols: np.ndarray | None = None   # [D, mb, Kb] int32, -1 pad
     bcsr_bvals: np.ndarray | None = None   # [D, mb, Kb, bs, bs]
     bcsr_on_bcols: np.ndarray | None = None  # on-part lowering (A_off stays ELL)
@@ -173,8 +175,9 @@ class DistOperator:
         Each device's (rows_local × [local|halo]) sparse block is re-tiled
         into dense ``bs×bs`` blocks; block-row padding never mixes devices
         because each device is lowered independently.  Once lowered,
-        :meth:`apply` routes through the MXU block contraction (kernel or
-        inline einsum) instead of the ELL gather.
+        :meth:`apply` routes through the block contraction
+        (:func:`~repro.kernels.spmv.bcsr.bcsr_apply`) instead of the ELL
+        gather.
         """
         from .csr import CSR, csr_to_bcsr
         D = self.n_devices
@@ -240,49 +243,22 @@ class DistOperator:
         if self.block_size:
             self.lower_bcsr(self.block_size)
 
-    @staticmethod
-    def _ell_product(cols, vals, src, use_kernel, interpret):
-        """ELL contraction of one split part against ``src`` ([n(,k)])."""
-        multi = src.ndim == 2
-        if use_kernel:
-            from ..kernels.spmv.spmv import ell_spmm, ell_spmv
-            if multi:
-                return ell_spmm(cols, vals, src, interpret=interpret)
-            return ell_spmv(cols, vals, src, interpret=interpret)
-        safe = jnp.maximum(cols, 0)
-        if multi:
-            contrib = jnp.where((cols >= 0)[..., None],
-                                vals[..., None] * src[safe], 0.0)
-        else:
-            contrib = jnp.where(cols >= 0, vals * src[safe], 0.0)
-        return contrib.sum(axis=1)
-
-    def _on_product(self, arrs, x_loc, use_kernel, interpret):
+    def _on_product(self, arrs, x_loc):
         """``A_on · x`` — the halo-free product that overlaps the exchange."""
         if "on_bcols" in arrs:
-            bcols, bvals = arrs["on_bcols"], arrs["on_bvals"]
-            if use_kernel:
-                from ..kernels.spmv.bcsr import bcsr_spmm, bcsr_spmv
-                fn = bcsr_spmm if x_loc.ndim == 2 else bcsr_spmv
-                y = fn(bcols, bvals, x_loc, interpret=interpret)
-            else:
-                from ..kernels.spmv.bcsr import bcsr_apply_ref
-                y = bcsr_apply_ref(bcols, bvals, x_loc)
+            y = bcsr_apply(arrs["on_bcols"], arrs["on_bvals"], x_loc)
             return y[: self.rows_local]
-        return self._ell_product(arrs["on_cols"], arrs["on_vals"], x_loc,
-                                 use_kernel, interpret)
+        return ell_apply(arrs["on_cols"], arrs["on_vals"], x_loc)
 
     def apply(self, arrs: dict[str, jnp.ndarray], x_loc: jnp.ndarray,
-              use_kernel: bool = False, interpret: bool = True,
               overlap: bool = True) -> jnp.ndarray:
         """Inside shard_map: halo exchange + local SpMV/SpMM for this device.
 
         ``arrs`` holds this device's slices of :meth:`device_arrays` (leading
         device dim already squeezed).  ``x_loc`` may be ``[local]`` (one RHS)
         or ``[local, k]`` (multi-RHS): the halo is exchanged once with the
-        RHS axis riding along.  Routing: BCSR block contraction when this
-        operator was :meth:`lower_bcsr`'d, else the ELL kernel
-        (``use_kernel``) or the inline gather form.
+        RHS axis riding along.  Routing: the block-ELL product when this
+        operator was :meth:`lower_bcsr`'d, else the ELL gather product.
 
         ``overlap=True`` (default) traces the exchange *before* the
         independent ``y_on = A_on·x`` product so XLA's async collectives can
@@ -293,7 +269,7 @@ class DistOperator:
         collective at all in either mode.
         """
         if self.halo_empty:
-            return self._on_product(arrs, x_loc, use_kernel, interpret)
+            return self._on_product(arrs, x_loc)
         psel = None if self.plan.pool_sel is None else arrs["psel"]
         if overlap:
             # issue the exchange first: `halo` is not consumed until the
@@ -301,24 +277,14 @@ class DistOperator:
             # product are dataflow-independent and free to overlap.
             halo = halo_exchange(x_loc, self.plan, arrs["send"],
                                  arrs["recv"], psel)
-            y = self._on_product(arrs, x_loc, use_kernel, interpret)
-            return y + self._ell_product(arrs["off_cols"], arrs["off_vals"],
-                                         halo, use_kernel, interpret)
+            y = self._on_product(arrs, x_loc)
+            return y + ell_apply(arrs["off_cols"], arrs["off_vals"], halo)
         halo = halo_exchange(x_loc, self.plan, arrs["send"], arrs["recv"], psel)
         xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
-        multi = x_loc.ndim == 2
         if "bcols" in arrs:
-            bcols, bvals = arrs["bcols"], arrs["bvals"]
-            if use_kernel:
-                from ..kernels.spmv.bcsr import bcsr_spmm, bcsr_spmv
-                fn = bcsr_spmm if multi else bcsr_spmv
-                y = fn(bcols, bvals, xfull, interpret=interpret)
-            else:
-                from ..kernels.spmv.bcsr import bcsr_apply_ref
-                y = bcsr_apply_ref(bcols, bvals, xfull)
+            y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
             return y[: self.rows_local]
-        return self._ell_product(arrs["cols"], arrs["vals"], xfull,
-                                 use_kernel, interpret)
+        return ell_apply(arrs["cols"], arrs["vals"], xfull)
 
     # ------------------------------------------------------- host-side layout
     def scatter_x(self, x: np.ndarray, dtype=None) -> np.ndarray:
@@ -482,7 +448,7 @@ class DistSpMV:
 
 def build_dist_spmv(A: CSR, n_pods: int, lanes: int, strategy: str,
                     mesh: jax.sharding.Mesh | None = None,
-                    dtype=jnp.float32, use_kernel: bool = False) -> DistSpMV:
+                    dtype=jnp.float32) -> DistSpMV:
     op = build_dist_operator(A, n_pods, lanes, strategy, dtype=dtype)
     if mesh is None:
         mesh = jax.make_mesh((n_pods, lanes), ("pod", "lane"))
@@ -495,12 +461,11 @@ def build_dist_spmv(A: CSR, n_pods: int, lanes: int, strategy: str,
         # squeeze the per-device leading dim added by shard_map
         x_loc = x_loc[0]
         a = jax.tree.map(lambda v: v[0], a)
-        return op.apply(a, x_loc, use_kernel=use_kernel,
-                        interpret=jax.default_backend() != "tpu")[None]
+        return op.apply(a, x_loc)[None]
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(dev_spec, dev_spec),
-                  out_specs=dev_spec, check_vma=False))
+        jax.shard_map(body, mesh=mesh, in_specs=(dev_spec, dev_spec),
+                      out_specs=dev_spec, check_vma=False))
 
     def matvec_dev(x_dev):
         return fn(jnp.asarray(x_dev, dtype=dtype), arrs)

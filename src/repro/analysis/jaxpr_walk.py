@@ -27,9 +27,10 @@ COLLECTIVE_PRIMS = frozenset({
 CANONICAL = {"reduce_scatter": "psum_scatter"}
 
 # local contraction work an overlapped exchange can hide behind: the ELL
-# gather form ends in a reduce_sum, the BCSR/MXU and dense-factor forms in
-# a dot_general
-CONTRACTION_PRIMS = frozenset({"reduce_sum", "dot_general"})
+# and BCSR products are a scan over their stored slots (fori_loop with a
+# static trip count), the dense-factor forms a dot_general, norms and dots
+# a reduce_sum
+CONTRACTION_PRIMS = frozenset({"reduce_sum", "dot_general", "scan"})
 
 
 def _as_jaxpr(obj):

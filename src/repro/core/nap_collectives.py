@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .comm_graph import CommGraph
-from .compat import axis_size as _axis_size
 from .schedules import Schedule, build as build_schedule
 
 # --------------------------------------------------------------------------
@@ -104,7 +103,7 @@ def hier_psum(x: jnp.ndarray, slow_axis: str, fast_axis: str,
         return jax.lax.psum(x, (slow_axis, fast_axis))
     if strategy != "nap3":
         raise ValueError(f"hier_psum: unknown strategy {strategy!r}")
-    fast = _axis_size(fast_axis)
+    fast = jax.lax.axis_size(fast_axis)
     shape = x.shape
     flat = x.reshape(-1)
     pad = (-flat.size) % fast
@@ -143,7 +142,7 @@ def hier_all_to_all(x: jnp.ndarray, slow_axis: str, fast_axis: str,
     pair (split over lanes), exactly the paper's three-step scheme:
     a2a(fast) regroup → a2a(slow) inter-pod → a2a(fast) redistribute.
     """
-    n_slow, n_fast = _axis_size(slow_axis), _axis_size(fast_axis)
+    n_slow, n_fast = jax.lax.axis_size(slow_axis), jax.lax.axis_size(fast_axis)
     total = n_slow * n_fast
     assert x.shape[0] == total, (x.shape, total)
     if strategy == "flat":

@@ -1,13 +1,11 @@
-"""Pallas TPU kernels: MXU-blocked BCSR SpMV / SpMM.
+"""Block-ELL (BCSR) local product: dense ``bs×bs`` blocks per stored slot.
 
-The ELL kernels in :mod:`repro.kernels.spmv.spmv` are VPU-gather bound —
-every nonzero costs one scalar gather + one FMA lane.  Operators with block
-structure (vector problems from :mod:`repro.amg.problems`, block-Jacobi
-levels) can instead store dense ``bs×bs`` blocks (bs ∈ {8, 16}) in a
-block-ELL layout and contract each block against a ``bs×k`` slab of the
-source with ``jax.lax.dot_general`` — dense math the MXU systolic array
-runs at matmul rate, amortizing the gather down to one block-row fetch per
-``bs²`` values.
+Operators with block structure (vector problems from
+:mod:`repro.amg.problems`, dense coarse levels) can store dense ``bs×bs``
+blocks (bs ∈ {8, 16}) in a block-ELL layout and contract each block
+against a ``bs×k`` slab of the source — dense math instead of one scalar
+gather per nonzero.  :func:`repro.kernels.spmv.ops.select_dist_kernel`
+decides per level whether a level is lowered this way.
 
 Layout (produced by :func:`repro.amg.csr.csr_to_bcsr`):
 
@@ -19,35 +17,18 @@ Layout (produced by :func:`repro.amg.csr.csr_to_bcsr`):
   * the source ``x`` is reshaped to [nb, bs(, k)] blocks; gathering block
     ``bcols[r, j]`` yields the ``bs(×k)`` slab the block multiplies.
 
-Grid: block rows.  Per step the kernel sees (BLOCK_BROWS, Kb) block ids +
-(BLOCK_BROWS, Kb, bs, bs) values in VMEM with the blocked X resident, and
-emits (BLOCK_BROWS, bs, k).
+Like :func:`repro.kernels.spmv.spmv.ell_apply` the product is plain XLA
+and accumulates one block slot at a time, so the gathered source is
+never materialised for all Kb slots at once.  It has no Pallas form:
+gathering blocks of a resident source inside a kernel is refused by
+Mosaic ("Only 2D gather is supported").
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 BLOCK_SIZES = (8, 16)
-
-
-def _bcsr_kernel(bcols_ref, bvals_ref, xb_ref, y_ref):
-    bcols = bcols_ref[...]        # (BR, Kb) int32
-    bvals = bvals_ref[...]        # (BR, Kb, bs, bs)
-    xb = xb_ref[...]              # (nb, bs, k) resident blocked source
-    safe = jnp.maximum(bcols, 0)
-    g = jnp.take(xb, safe.reshape(-1), axis=0)          # (BR*Kb, bs, k)
-    g = g.reshape(bcols.shape + xb.shape[1:])           # (BR, Kb, bs, k)
-    g = jnp.where((bcols >= 0)[..., None, None], g, 0.0)
-    # (BR, Kb, bs, bs) × (BR, Kb, bs, k) → (BR, Kb, bs, k): batch over the
-    # (block-row, slot) dims, contract the trailing bs — the MXU path
-    contrib = jax.lax.dot_general(
-        bvals, g, (((3,), (2,)), ((0, 1), (0, 1))),
-        preferred_element_type=bvals.dtype)
-    y_ref[...] = contrib.sum(axis=1)                    # (BR, bs, k)
 
 
 def _block_x(x: jnp.ndarray, bs: int) -> jnp.ndarray:
@@ -61,58 +42,25 @@ def _block_x(x: jnp.ndarray, bs: int) -> jnp.ndarray:
     return x.reshape(-1, bs, k)
 
 
-@functools.partial(jax.jit, static_argnames=("block_brows", "interpret"))
-def bcsr_spmm(bcols: jnp.ndarray, bvals: jnp.ndarray, x: jnp.ndarray,
-              block_brows: int = 32, interpret: bool = True) -> jnp.ndarray:
-    """Y = A·X with A in block-ELL BCSR form and X of shape ``[m, k]``.
-
-    ``bcols``: [mb, Kb] int32 (-1 pad), ``bvals``: [mb, Kb, bs, bs].
-    Returns [mb * bs, k] — callers slice back to the true row count.
-    """
+def bcsr_apply(bcols: jnp.ndarray, bvals: jnp.ndarray,
+               x: jnp.ndarray) -> jnp.ndarray:
+    """Y = A·X with A in block-ELL form (``bcols`` [mb, Kb], ``bvals``
+    [mb, Kb, bs, bs]) and X of shape [m] or [m, k].  Returns
+    [mb·bs] or [mb·bs, k] — callers slice back to the true row count."""
+    single = x.ndim == 1
     mb, Kb = bcols.shape
     bs = bvals.shape[-1]
-    k = x.shape[1]
-    if mb == 0 or Kb == 0 or x.shape[0] == 0 or k == 0:
-        return jnp.zeros((mb * bs, k), dtype=bvals.dtype)
     xb = _block_x(x, bs)
-    br = min(block_brows, max(1, mb))
-    pad = (-mb) % br
-    if pad:
-        bcols = jnp.pad(bcols, ((0, pad), (0, 0)), constant_values=-1)
-        bvals = jnp.pad(bvals, ((0, pad), (0, 0), (0, 0), (0, 0)))
-    grid = (bcols.shape[0] // br,)
-    y = pl.pallas_call(
-        _bcsr_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, Kb), lambda i: (i, 0)),
-            pl.BlockSpec((br, Kb, bs, bs), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec(xb.shape, lambda i: (0, 0, 0)),   # X resident
-        ],
-        out_specs=pl.BlockSpec((br, bs, k), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bcols.shape[0], bs, k), bvals.dtype),
-        interpret=interpret,
-    )(bcols, bvals, xb)
-    return y[:mb].reshape(mb * bs, k)
+    y = jnp.zeros((mb, bs, xb.shape[-1]), dtype=bvals.dtype)
+    if Kb and xb.shape[0]:
+        def slot(j, y):
+            c = jax.lax.dynamic_index_in_dim(bcols, j, axis=1, keepdims=False)
+            blk = jax.lax.dynamic_index_in_dim(bvals, j, axis=1,
+                                               keepdims=False)
+            g = xb[jnp.maximum(c, 0)]                        # [mb, bs, k]
+            contrib = jnp.einsum("rij,rjk->rik", blk, g)
+            return y + jnp.where((c >= 0)[:, None, None], contrib, 0.0)
 
-
-def bcsr_spmv(bcols: jnp.ndarray, bvals: jnp.ndarray, x: jnp.ndarray,
-              block_brows: int = 32, interpret: bool = True) -> jnp.ndarray:
-    """y = A·x (single RHS) through the same MXU block contraction."""
-    return bcsr_spmm(bcols, bvals, x[:, None], block_brows=block_brows,
-                     interpret=interpret)[:, 0]
-
-
-def bcsr_apply_ref(bcols, bvals, x):
-    """Pure-jnp oracle of the block contraction (matches the kernel's
-    summation order; [m] or [m, k] source, returns [mb*bs(, k)])."""
-    single = x.ndim == 1
-    bs = bvals.shape[-1]
-    xb = _block_x(x, bs)
-    safe = jnp.maximum(bcols, 0)
-    g = jnp.take(xb, safe.reshape(-1), axis=0).reshape(
-        bcols.shape + xb.shape[1:])
-    g = jnp.where((bcols >= 0)[..., None, None], g, 0.0)
-    contrib = jnp.einsum("rsij,rsjk->rsik", bvals, g)
-    y = contrib.sum(axis=1).reshape(-1, xb.shape[-1])
+        y = jax.lax.fori_loop(0, Kb, slot, y)
+    y = y.reshape(mb * bs, -1)
     return y[:, 0] if single else y

@@ -1,39 +1,15 @@
-"""Public jit'd wrappers for the sparse local-SpMV kernel tier.
+"""Per-level local-product layout selection.
 
-``spmv``/``spmm`` dispatch between the Pallas kernels and the pure-jnp
-oracles; :func:`select_local_kernel` is the layout heuristic the
-distributed solve phase uses to pick, per level, between the VPU-gather
-ELL kernels and the MXU-blocked BCSR kernel.
+:func:`select_local_kernel` / :func:`select_dist_kernel` are the layout
+heuristic the distributed solve phase uses to pick, per level, between
+the ELL gather product (:func:`repro.kernels.spmv.spmv.ell_apply`) and the
+block-ELL product (:func:`repro.kernels.spmv.bcsr.bcsr_apply`).
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 
-from .bcsr import BLOCK_SIZES, bcsr_spmm, bcsr_spmv
-from .ref import ell_spmm_ref, ell_spmv_ref
-from .spmv import ell_spmm, ell_spmv
-
-
-def spmv(cols, vals, x, *, use_kernel: bool = True, block_rows: int = 256,
-         interpret: bool | None = None):
-    """ELL SpMV.  ``interpret=None`` → interpret on CPU, compiled on TPU."""
-    if not use_kernel:
-        return ell_spmv_ref(cols, vals, x)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return ell_spmv(cols, vals, x, block_rows=block_rows, interpret=interpret)
-
-
-def spmm(cols, vals, x, *, use_kernel: bool = True, block_rows: int = 256,
-         interpret: bool | None = None):
-    """Native multi-RHS ELL SpMM (``x``: [m, k]) — one pass over A serves
-    every column; the fallback oracle keeps identical summation order."""
-    if not use_kernel:
-        return ell_spmm_ref(cols, vals, x)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return ell_spmm(cols, vals, x, block_rows=block_rows, interpret=interpret)
+from .bcsr import BLOCK_SIZES
 
 
 # --------------------------------------------------------------------------
@@ -136,5 +112,4 @@ def select_dist_kernel(cols_stack: np.ndarray,
     return best
 
 
-__all__ = ["spmv", "spmm", "bcsr_spmv", "bcsr_spmm", "select_local_kernel",
-           "select_dist_kernel", "MXU_ADVANTAGE"]
+__all__ = ["select_local_kernel", "select_dist_kernel", "MXU_ADVANTAGE"]

@@ -1,134 +1,46 @@
-"""Pallas TPU kernels: row-blocked ELL SpMV and native multi-RHS SpMM.
+"""The local ELL product every distributed apply runs on the device.
 
-TPU adaptation of the paper's SpMV hot loop (DESIGN.md §2).  The sparse
-matrix is stored in ELL (fixed K nonzeros per padded row) — the layout the
-distributed solve path already uses, and a gather-friendly layout for the
-VPU.  Tiling:
+The sparse block is stored in ELL form (fixed K slots per padded row,
+``cols == -1`` padding) — the layout the distributed solve path lowers
+every operator to.  :func:`ell_apply` contracts it against a source of
+shape ``[m]`` (one right-hand side) or ``[m, k]`` (the native multi-RHS
+form: one pass over ``cols``/``vals`` serves all k columns).
 
-  * grid over row blocks; per step the kernel sees a (BLOCK_ROWS, K) tile of
-    column ids + values in VMEM,
-  * the source vector ``x`` is resident in VMEM for every step (BlockSpec
-    with a constant index_map): AMG level vectors after partitioning are
-    ≤ a few hundred KB per device, far under the ~16 MB v5e VMEM budget,
-  * gather x[cols] + multiply-accumulate over K on the VPU (8×128 lanes);
-    rows are padded to a multiple of 8 and K left at its natural size.
+The product is plain XLA: a ``fori_loop`` over the K slots, each step
+gathering one ``[n(, k)]`` slab of the source and accumulating it.  The
+obvious one-shot form ``(vals[..., None] * x[cols]).sum(1)`` materialises
+``[n, K(, k)]``; compiled for one v5e chip at 1,124,864 rows × K=27 that
+needed 1.73 GB of scratch for k=1 and 5.83 GB for k=8, while the
+slot-at-a-time form needs about 6 MB for either (``tests/
+test_tpu_compile.py`` holds it there).
 
-Two batching regimes:
-
-  * :func:`ell_spmv` — one right-hand side, ``x`` of shape ``[m]``.
-  * :func:`ell_spmm` — the native multi-RHS form, ``x`` of shape ``[m, k]``:
-    the kernel gathers whole *rows* of X and accumulates ``(BLOCK_ROWS, K,
-    k)`` contributions, so ONE pass over ``cols``/``vals`` serves all k
-    right-hand sides.  This is what coalesced serving batches route through
-    instead of ``jax.vmap(ell_spmv)`` (which re-reads A's nonzeros k times).
-
-Degenerate shapes are short-circuited before ``pallas_call``: K == 0 (empty
-coarse operator rows) and n == 0 / m == 0 return exact zeros instead of
-building a zero-size BlockSpec, and tiny n no longer over-pads past the
-``max(8, n)`` block-rows clamp.
-
-The MXU-blocked BCSR variant (dense bs×bs blocks contracted via
-``jax.lax.dot_general`` on the systolic array) lives in
-:mod:`repro.kernels.spmv.bcsr`; the per-level choice between the two layouts
-is :func:`repro.kernels.spmv.ops.select_local_kernel`.
+A Pallas version of this product does not lower for the TPU: Mosaic has
+no arbitrary gather from a VMEM-resident vector (``jnp.take`` inside a
+kernel is refused with "Only 2D gather is supported").
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 
-def _spmv_kernel(cols_ref, vals_ref, x_ref, y_ref):
-    cols = cols_ref[...]          # (BLOCK_ROWS, K) int32
-    vals = vals_ref[...]          # (BLOCK_ROWS, K)
-    x = x_ref[...]                # (m,) resident vector
-    safe = jnp.maximum(cols, 0)
-    gathered = jnp.take(x, safe, axis=0)          # VPU gather
-    contrib = jnp.where(cols >= 0, vals * gathered, 0.0)
-    y_ref[...] = contrib.sum(axis=1)
-
-
-def _spmm_kernel(cols_ref, vals_ref, x_ref, y_ref):
-    cols = cols_ref[...]          # (BLOCK_ROWS, K) int32
-    vals = vals_ref[...]          # (BLOCK_ROWS, K)
-    x = x_ref[...]                # (m, k) resident RHS block
-    safe = jnp.maximum(cols, 0)
-    # gather whole rows of X once per stored nonzero: (BLOCK_ROWS, K, k)
-    gathered = jnp.take(x, safe.reshape(-1), axis=0)
-    gathered = gathered.reshape(cols.shape + (x.shape[1],))
-    contrib = jnp.where((cols >= 0)[..., None],
-                        vals[..., None] * gathered, 0.0)
-    y_ref[...] = contrib.sum(axis=1)              # (BLOCK_ROWS, k)
-
-
-def _row_blocking(n: int, block_rows: int) -> tuple[int, int]:
-    """(block_rows, row_padding) for an n-row ELL operand: blocks of at
-    least 8 rows (VPU sublane), never over-padding tiny n past one block."""
-    br = min(block_rows, max(8, n))
-    return br, (-n) % br
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def ell_spmv(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray,
-             block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
-    """y = A·x with A in padded ELL form (cols==-1 padding)."""
-    n, k = cols.shape
-    if n == 0 or k == 0 or x.shape[0] == 0:
-        # empty rows / empty operator / empty source: exact zeros — a
-        # (br, 0) BlockSpec or an empty-x gather would crash pallas_call
-        return jnp.zeros((n,), dtype=vals.dtype)
-    br, pad = _row_blocking(n, block_rows)
-    if pad:
-        cols = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=-1)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-    grid = (cols.shape[0] // br,)
-    y = pl.pallas_call(
-        _spmv_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, k), lambda i: (i, 0)),
-            pl.BlockSpec((br, k), lambda i: (i, 0)),
-            pl.BlockSpec((x.shape[0],), lambda i: (0,)),  # x resident
-        ],
-        out_specs=pl.BlockSpec((br,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((cols.shape[0],), vals.dtype),
-        interpret=interpret,
-    )(cols, vals, x)
-    return y[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def ell_spmm(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray,
-             block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
-    """Y = A·X with A in padded ELL form and X of shape ``[m, k]``.
-
-    One pass over ``cols``/``vals`` serves all k columns: the kernel gathers
-    rows of X and accumulates a (BLOCK_ROWS, K, k) contribution block, so
-    A's nonzeros are read once instead of once per RHS as under
-    ``jax.vmap(ell_spmv)``.
-    """
+def ell_apply(cols: jnp.ndarray, vals: jnp.ndarray,
+              x: jnp.ndarray) -> jnp.ndarray:
+    """y = A·x with A in padded ELL form: ``cols``/``vals`` are [n, K],
+    ``x`` is [m] or [m, k] (m covers every valid column id).  Returns
+    [n] or [n, k]."""
     n, K = cols.shape
-    m, k = x.shape
-    if n == 0 or K == 0 or m == 0 or k == 0:
-        return jnp.zeros((n, k), dtype=vals.dtype)
-    br, pad = _row_blocking(n, block_rows)
-    if pad:
-        cols = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=-1)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-    grid = (cols.shape[0] // br,)
-    y = pl.pallas_call(
-        _spmm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, K), lambda i: (i, 0)),
-            pl.BlockSpec((br, K), lambda i: (i, 0)),
-            pl.BlockSpec((m, k), lambda i: (0, 0)),       # X resident
-        ],
-        out_specs=pl.BlockSpec((br, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cols.shape[0], k), vals.dtype),
-        interpret=interpret,
-    )(cols, vals, x)
-    return y[:n]
+    y = jnp.zeros((n,) + x.shape[1:], dtype=vals.dtype)
+    if K == 0 or x.shape[0] == 0:
+        return y
+
+    def slot(j, y):
+        c = jax.lax.dynamic_index_in_dim(cols, j, axis=1, keepdims=False)
+        v = jax.lax.dynamic_index_in_dim(vals, j, axis=1, keepdims=False)
+        valid = c >= 0
+        g = x[jnp.maximum(c, 0)]
+        if x.ndim == 2:
+            v, valid = v[:, None], valid[:, None]
+        return y + jnp.where(valid, v * g, 0.0)
+
+    return jax.lax.fori_loop(0, K, slot, y)

@@ -226,6 +226,8 @@ def main():
                          "tenant); only with --listen")
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.solver == "amg":
         if args.listen:
             run_listen(args)

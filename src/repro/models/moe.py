@@ -122,8 +122,7 @@ def moe_ep_shardmap(p, cfg, x, mesh, dp_axes=("data",), ep_axes=("data",),
     # d_ff sharding over tp_axis rides on dims 2 (gate/up) and 1 (down)
     w_spec = P(w_spec[0], None, tp_axis)
     wd_spec = P(wd_spec[0], tp_axis, None)
-    from ..core.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, wd_spec),
         out_specs=x_spec, check_vma=False,
@@ -141,9 +140,8 @@ def moe_ffn_ep(p, cfg, x, mesh_axes=("model",), nap: bool = False,
     """
     T, d = x.shape
     m = 1
-    from ..core.compat import axis_size
     for ax in mesh_axes:
-        m *= axis_size(ax)
+        m *= jax.lax.axis_size(ax)
     E = cfg.n_experts
     e_loc = E // m
     probs, sel = _route(x, p["router"], cfg.top_k)

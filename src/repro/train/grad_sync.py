@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..core.compat import axis_size
 from ..core.nap_collectives import hier_psum
 
 
@@ -43,8 +42,8 @@ def hier_grad_sync(grads, slow_axis: str, fast_axis: str,
     per-device grads.  ``error_feedback`` must match ``grads`` (zeros to
     start) when ``compress_slow``.
     """
-    n_slow = axis_size(slow_axis)
-    n_fast = axis_size(fast_axis)
+    n_slow = jax.lax.axis_size(slow_axis)
+    n_fast = jax.lax.axis_size(fast_axis)
     denom = float(n_slow * n_fast)
 
     if strategy == "flat" or not compress_slow:

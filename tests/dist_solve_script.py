@@ -62,12 +62,20 @@ def main():
     assert history_diff(res_h.residuals, res_d.residuals) < TOL
     print("OK auto_select")
 
-    # Pallas ELL kernel route (interpret mode off-TPU) inside the fused cycle
-    dh_k = DistHierarchy.build(h, N_PODS, LANES, strategy="nap3",
-                               use_kernel=True, interpret=True)
-    pcg_k = pcg(h, b, tol=1e-5, maxiter=12, backend="dist", dist=dh_k)
-    assert history_diff(pcg_h.residuals, pcg_k.residuals) < TOL
-    print("OK pallas_path")
+    # block-ELL route inside the fused cycle: force every smoothing level's
+    # A onto BCSR (bs=8) and hold PCG to the host history
+    import repro.amg.dist_solve as ds
+    pick = ds.select_dist_kernel
+    ds.select_dist_kernel = lambda cols: dict(pick(cols), kernel="bcsr",
+                                              block_size=8)
+    try:
+        dh_b = DistHierarchy.build(h, N_PODS, LANES, strategy="nap3")
+    finally:
+        ds.select_dist_kernel = pick
+    assert all(dl.A.local_kernel == "bcsr" for dl in dh_b.levels[:-1])
+    pcg_b = pcg(h, b, tol=1e-5, maxiter=12, backend="dist", dist=dh_b)
+    assert history_diff(pcg_h.residuals, pcg_b.residuals) < TOL
+    print("OK bcsr_path")
 
     # chebyshev smoother parity through the same fused program
     oc = SolveOptions(smoother="chebyshev")

@@ -25,12 +25,11 @@ mesh = jax.make_mesh((N_PODS, LANES), ("pod", "lane"))
 DEV = P(("pod", "lane"))
 
 
-from repro.core.compat import shard_map  # noqa: E402
 
 
 def shmap(f, n_in, out_specs=DEV):
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=(DEV,) * n_in,
-                             out_specs=out_specs, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(DEV,) * n_in,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def check_hier_psum():

@@ -43,7 +43,6 @@ def dh11():
 def test_hier_collective_golden_signatures_1x1():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
-    from repro.core.compat import shard_map
     from repro.core.nap_collectives import (GATHER_SIGNATURES,
                                             REDUCE_SIGNATURES,
                                             hier_all_gather, hier_psum)
@@ -51,8 +50,8 @@ def test_hier_collective_golden_signatures_1x1():
     mesh = jax.make_mesh((1, 1), ("pod", "lane"))
 
     def trace(fn):
-        sm = shard_map(fn, mesh=mesh, in_specs=P(("pod", "lane")),
-                       out_specs=P(("pod", "lane")), check_vma=False)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=P(("pod", "lane")),
+                           out_specs=P(("pod", "lane")), check_vma=False)
         return jax.make_jaxpr(sm)(jnp.zeros((1, 8)))
 
     for strat, expect in REDUCE_SIGNATURES.items():
@@ -143,13 +142,12 @@ def test_overlap_independence_taint_sweep():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
     from repro.analysis import check_overlap_independence
-    from repro.core.compat import shard_map
     P = jax.sharding.PartitionSpec
     mesh = jax.make_mesh((1,), ("ax",))
 
     def trace(fn):
-        sm = shard_map(fn, mesh=mesh, in_specs=P("ax"), out_specs=P(),
-                       check_vma=False)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=P("ax"), out_specs=P(),
+                           check_vma=False)
         return jax.make_jaxpr(sm)(jnp.zeros((8,)))
 
     def serial(x):

@@ -23,7 +23,7 @@ EXPECTED = [
     "OK solve_standard", "OK pcg_standard",
     "OK solve_nap2", "OK pcg_nap2",
     "OK solve_nap3", "OK pcg_nap3",
-    "OK auto_select", "OK pallas_path", "OK chebyshev",
+    "OK auto_select", "OK bcsr_path", "OK chebyshev",
     "OK cycle_smoother_parity", "OK overlap_parity", "OK empty_halo",
     "OK comm_audit", "OK dist_setup_cycles", "OK multi_rhs",
     "OK streaming_refresh",

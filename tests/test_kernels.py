@@ -1,5 +1,6 @@
-"""Pallas kernel validation: interpret=True vs pure-jnp oracles, swept over
-shapes/dtypes (per-kernel allclose requirement)."""
+"""Kernel validation against pure-jnp oracles, swept over shapes/dtypes:
+the local ELL product the distributed applies run, and the flash-attention
+Pallas kernel in interpret mode."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,9 +8,8 @@ import pytest
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ops import attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.spmv.ops import spmv
 from repro.kernels.spmv.ref import ell_spmv_ref
-from repro.kernels.spmv.spmv import ell_spmv
+from repro.kernels.spmv.spmv import ell_apply
 
 
 # ------------------------------------------------------------------- spmv
@@ -32,7 +32,7 @@ def test_spmv_kernel_matches_ref(n, m, k, dtype):
     vals = vals.astype(jnp.dtype(dtype))
     x = x.astype(jnp.dtype(dtype))
     ref = ell_spmv_ref(cols, vals, x)
-    out = ell_spmv(cols, vals, x, interpret=True)
+    out = ell_apply(cols, vals, x)
     tol = 1e-5 if dtype == "float32" else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
@@ -50,18 +50,8 @@ def test_spmv_matches_csr_matvec():
         cols[i, : s.stop - s.start] = A.indices[s]
         vals[i, : s.stop - s.start] = A.data[s]
     x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
-    y = spmv(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
+    y = ell_apply(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(y), A.matvec(x), rtol=2e-4, atol=2e-4)
-
-
-def test_spmv_block_rows_sweep():
-    rng = np.random.default_rng(5)
-    cols, vals, x = _random_ell(rng, 200, 128, 5, np.float32)
-    ref = ell_spmv_ref(cols, vals, x)
-    for br in (8, 32, 64, 512):
-        out = ell_spmv(cols, vals, x, block_rows=br, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
-                                   atol=1e-5)
 
 
 # -------------------------------------------------------------- attention

@@ -1,0 +1,101 @@
+"""Compile the chip path for a TPU v5e chip that is described, not attached.
+
+Nothing here runs on a device: each test lowers and compiles with the
+TPU's own compiler, which refuses what the chip would refuse (a kernel
+Mosaic cannot lower, a program that does not fit), and reads back the
+scratch memory XLA plans for it.  The local products are compiled at
+HPCG's 104³ size, about two seconds each; the fused PCG programs at a
+small grid, since their shapes come from a host setup.
+"""
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
+                          SingleDeviceSharding)
+
+from repro.kernels.spmv.bcsr import bcsr_apply  # noqa: E402
+from repro.kernels.spmv.spmv import ell_apply  # noqa: E402
+
+ROWS = 104 ** 3        # HPCG's reference local grid: 1,124,864 rows
+K = 27                 # the 27-point stencil's ELL width
+# ell_apply plans 6.1 MB (k=1) and 5.8 MB (k=8) of scratch at this size:
+# each slot's gather fuses into the accumulation.  The one-shot gather
+# form it replaced planned 1.73 GB (k=1) and 5.83 GB (k=8).
+ELL_TEMP_LIMIT = 8 << 20
+BCSR_TEMP_LIMIT = {1: 73_000_000, 8: 2_215_000_000}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # a described compile cannot be read back from the persistent cache
+    # without a chip; keep it out of any cache a caller has turned on
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    return compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_ell_apply_compiles_at_hpcg_size(one_chip, k):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    x = s((ROWS,) if k == 1 else (ROWS, k), jnp.float32)
+    mem = _compile(ell_apply, s((ROWS, K), jnp.int32),
+                   s((ROWS, K), jnp.float32), x)
+    assert mem.temp_size_in_bytes <= ELL_TEMP_LIMIT, mem
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_bcsr_apply_compiles_at_hpcg_size(one_chip, k):
+    """The 27-point stencil blocked at bs=8: 27 blocks per block row."""
+    bs, mb = 8, ROWS // 8
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    x = s((ROWS,) if k == 1 else (ROWS, k), jnp.float32)
+    mem = _compile(bcsr_apply, s((mb, K), jnp.int32),
+                   s((mb, K, bs, bs), jnp.float32), x)
+    # what XLA plans today: the gathered [mb, bs, k] slab is tile-padded
+    # on its two minor dims (72 MB at k=1, 2.21 GB at k=8); it must not grow
+    assert mem.temp_size_in_bytes <= BCSR_TEMP_LIMIT[k], mem
+
+
+@pytest.mark.parametrize("name", ["pcg_step", "pcg_step_m"])
+def test_fused_pcg_program_compiles(topo, name):
+    """The whole fused PCG iteration of a 1-chip session, with its
+    programs built on a mesh of the described chip."""
+    from repro.amg import SolveOptions, setup
+    from repro.amg.dist_solve import DEV_AXES, DistHierarchy
+    from repro.amg.problems import laplace_3d
+
+    # a private lowering: its mesh is swapped for the described chip's
+    # before any program is built
+    dh = DistHierarchy.build(setup(laplace_3d(16)), 1, 1)
+    dh.mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), DEV_AXES)
+    progs, arrs = dh.programs(SolveOptions())
+    dev = NamedSharding(dh.mesh, PartitionSpec(DEV_AXES))
+    rep = NamedSharding(dh.mesh, PartitionSpec())
+    arrs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), arrs)
+    n = dh.levels[0].A.plan.local_n
+    k = (8,) if name.endswith("_m") else ()
+    vec = jax.ShapeDtypeStruct((1, n) + k, jnp.float32, sharding=dev)
+    rz = jax.ShapeDtypeStruct(k, jnp.float32, sharding=rep)
+    compiled = progs[name].lower(vec, vec, vec, rz, arrs).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
