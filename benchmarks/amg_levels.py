@@ -1,10 +1,11 @@
 """Fig. 2/4: per-level setup and solve cost, split into measured local
 compute (this CPU) and modeled communication, for RS and SA hierarchies."""
 import time
+from collections import Counter
 
 import numpy as np
 
-from repro.amg import setup, vcycle
+from repro.amg import setup, spans, vcycle
 from repro.amg.dist import analyze_hierarchy, phase_costs
 from repro.amg.problems import laplace_3d
 from repro.core import BLUE_WATERS, Topology
@@ -15,11 +16,16 @@ def rows(n=16, n_nodes=16, ppn=16):
     topo = Topology(n_nodes=n_nodes, ppn=ppn)
     out = []
     for solver in ("rs", "sa"):
+        spans.clear()
         h = setup(A, solver=solver)
+        setup_ns = Counter()                 # host setup time of each level
+        for s in spans.recent():
+            if s.name.startswith("amg.setup."):
+                setup_ns[s.attrs["level"]] += s.duration_ns
         ops = analyze_hierarchy(h, topo, BLUE_WATERS)
         costs = phase_costs(ops, h.n_levels)
         for l in range(h.n_levels):
-            local_us = h.levels[l].setup_seconds * 1e6 / topo.n_procs
+            local_us = setup_ns[l] / 1e3 / topo.n_procs
             comm_us = costs["setup"][l]["selected"] * 1e6
             out.append((f"fig2_{solver}_setup_L{l}",
                         local_us + comm_us,

@@ -10,7 +10,7 @@ import time
 
 import numpy as np
 
-from repro.amg import setup, vcycle
+from repro.amg import setup, spans, vcycle
 from repro.amg.dist import analyze_hierarchy
 from repro.amg.problems import grad_div_3d, laplace_3d
 from repro.core import BLUE_WATERS, Topology
@@ -36,13 +36,15 @@ def _measure_local(A, h):
     t0 = time.perf_counter()
     vcycle(h, b)
     solve_local = time.perf_counter() - t0
-    setup_local = sum(l.setup_seconds for l in h.levels)
+    setup_local = sum(s.duration_ns for s in spans.recent()
+                      if s.name.startswith("amg.setup.")) / 1e9
     return setup_local, solve_local
 
 
 def rows(system="graddiv", machine=BLUE_WATERS, weak=False):
     out = []
     A = grad_div_3d(10) if system == "graddiv" else laplace_3d(18)
+    spans.clear()
     h = setup(A, solver="rs")
     setup_local, solve_local = _measure_local(A, h)
     procs_list = (256, 512, 1024, 2048, 4096)

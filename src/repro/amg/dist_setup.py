@@ -48,7 +48,6 @@ records, usable without any device mesh) and :func:`dist_setup`
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -59,6 +58,7 @@ from ..core.perf_model import TPU_V5E
 from .csr import CSR
 from .dist import matrix_comm_graph
 from .hierarchy import strength_stage
+from .spans import span
 from .splitting import CPOINT, FPOINT, UNASSIGNED, _drop_diag
 
 SETUP_STRATEGIES = ("standard", "nap2", "nap3")
@@ -249,8 +249,6 @@ class SetupCommRecord:
     # row exchange is in flight, C_off = A·B_halo lands after it
     on_nnz: int = 0              # nnz of all ranks' C_on
     off_nnz: int = 0             # nnz of all ranks' C_off
-    on_seconds: float = 0.0      # measured wall time of the C_on products
-    off_seconds: float = 0.0     # measured wall time of C_off + merge
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -301,11 +299,8 @@ def dist_spgemm(Ab: BlockMatrix, Bb: BlockMatrix, *,
         return blk.indices[sl], blk.data[sl]
 
     D = Ab.part.topo.n_procs
-    t0 = time.perf_counter()
     on_blocks = [Ab.blocks[d].spgemm(Bb.blocks[d]) for d in range(D)]
-    on_seconds = time.perf_counter() - t0
     res = matrix_halo_exchange(plan, get_row)
-    t0 = time.perf_counter()
     out_blocks = []
     off_nnz = 0
     for d in range(D):
@@ -316,7 +311,6 @@ def dist_spgemm(Ab: BlockMatrix, Bb: BlockMatrix, *,
             out_blocks.append(on_blocks[d].add(C_off))
         else:
             out_blocks.append(on_blocks[d])
-    off_seconds = time.perf_counter() - t0
     if records is not None:
         records.append(SetupCommRecord(
             level=level, op=op, strategy=strat, modeled=times,
@@ -324,8 +318,7 @@ def dist_spgemm(Ab: BlockMatrix, Bb: BlockMatrix, *,
             intra_msgs=res.intra_msgs, intra_bytes=res.intra_bytes,
             seconds=res.seconds,
             n_halo_rows=sum(len(h) for h in res.halo),
-            on_nnz=sum(b.nnz for b in on_blocks), off_nnz=off_nnz,
-            on_seconds=on_seconds, off_seconds=off_seconds))
+            on_nnz=sum(b.nnz for b in on_blocks), off_nnz=off_nnz))
     return BlockMatrix(out_blocks, Ab.part)
 
 
@@ -426,7 +419,6 @@ class PartitionedLevel:
     P: BlockMatrix | None = None
     R: BlockMatrix | None = None
     AP: BlockMatrix | None = None
-    setup_seconds: float = 0.0
     # NAP schedules of this level's Galerkin row exchanges, keyed by op
     # ("spgemm_AP"/"spgemm_PtAP" → (strategy, MatrixHaloPlan)) — retained
     # so streaming value refreshes replay the products through the
@@ -463,69 +455,73 @@ def dist_setup_partitioned(
     records: list[SetupCommRecord] = []
     l = 0
     while plevels[l].A.nrows > max_coarse and l + 1 < max_levels:
-        t0 = time.perf_counter()
         Ab = plevels[l].A
         part = Ab.part
         n = Ab.nrows
         ranges = [part.local_range(d) for d in range(D)]
         # -- strength: row-local, exact per block
-        Sb = BlockMatrix([strength_stage(blk, solver, theta)
-                          for blk in Ab.blocks], part)
+        with span("amg.setup.strength", level=l, rows=n):
+            Sb = BlockMatrix([strength_stage(blk, solver, theta)
+                              for blk in Ab.blocks], part)
         # -- splitting: symmetrize (transpose exchange), optional distance-2
         #    squaring (NAP matrix-row exchange), then the partitioned PMIS
-        Stb = transpose_blocks(Sb, part)
-        Gb = _sym_graph_blocks(Sb, Stb)
-        if aggressive:
-            GG = dist_spgemm(Gb, Gb, params=params, strategy=strategy,
-                             strategies=strategies, op="spgemm_S2",
-                             level=l, records=records,
-                             plan_cache=plevels[l].plans)
-            Gb = _sym_graph_blocks(GG, transpose_blocks(GG, part))
-        # w = (#strong transpose connections) + replicated random tiebreak —
-        # every rank draws the same deterministic stream, as an SPMD code
-        # would, so the splitting matches the host bit-for-bit
-        rng_w = np.random.default_rng(seed + l).random(n)
-        w_parts = [np.diff(Stb.blocks[d].indptr)[lo:hi].astype(np.float64)
-                   + rng_w[lo:hi] for d, (lo, hi) in enumerate(ranges)]
-        status = _dist_pmis(Gb, w_parts, part)
+        with span("amg.setup.splitting", level=l, rows=n):
+            Stb = transpose_blocks(Sb, part)
+            Gb = _sym_graph_blocks(Sb, Stb)
+            if aggressive:
+                GG = dist_spgemm(Gb, Gb, params=params, strategy=strategy,
+                                 strategies=strategies, op="spgemm_S2",
+                                 level=l, records=records,
+                                 plan_cache=plevels[l].plans)
+                Gb = _sym_graph_blocks(GG, transpose_blocks(GG, part))
+            # w = (#strong transpose connections) + replicated random
+            # tiebreak — every rank draws the same deterministic stream, as
+            # an SPMD code would, so the splitting matches the host
+            # bit-for-bit
+            rng_w = np.random.default_rng(seed + l).random(n)
+            w_parts = [np.diff(Stb.blocks[d].indptr)[lo:hi].astype(np.float64)
+                       + rng_w[lo:hi] for d, (lo, hi) in enumerate(ranges)]
+            status = _dist_pmis(Gb, w_parts, part)
         n_c = sum(int((st == CPOINT).sum()) for st in status)
         if n_c in (0, n):
             break  # coarsening stalled
         # -- interpolation: per-block direct interpolation; C/F status and
         #    the fine→coarse map at halo columns come from vector gathers
-        c_counts = [int((st == CPOINT).sum()) for st in status]
-        c_offsets = np.concatenate([[0], np.cumsum(c_counts)])[:-1]
-        cmap_parts = [np.cumsum(st == CPOINT) - 1 + c_offsets[d]
-                      for d, st in enumerate(status)]
-        P_blocks = []
-        for d, (lo, hi) in enumerate(ranges):
-            halo = Sb.blocks[d].offproc_columns(lo, hi, lo, hi)
-            row_status = np.full(n, FPOINT, dtype=np.int64)
-            row_status[lo:hi] = status[d]
-            col_status = np.full(n, FPOINT, dtype=np.int64)
-            col_status[lo:hi] = status[d]
-            col_status[halo] = _gather(status, part, halo)
-            col_cmap = np.zeros(n, dtype=np.int64)
-            col_cmap[lo:hi] = cmap_parts[d]
-            col_cmap[halo] = _gather(cmap_parts, part, halo)
-            P_blocks.append(direct_interpolation(
-                Ab.blocks[d], Sb.blocks[d], row_status,
-                col_status=col_status, cmap=col_cmap, nc=n_c))
-        Pb = BlockMatrix(P_blocks, part)
-        cpart = Partition.balanced(n_c, topo)
-        Rb = transpose_blocks(Pb, cpart)
-        # -- Galerkin triple product: the two NAP matrix-row exchanges
-        APb = dist_spgemm(Ab, Pb, params=params, strategy=strategy,
-                          strategies=strategies, op="spgemm_AP",
-                          level=l, records=records,
-                          plan_cache=plevels[l].plans)
-        Acb = dist_spgemm(Rb, APb, params=params, strategy=strategy,
-                          strategies=strategies, op="spgemm_PtAP",
-                          level=l, records=records,
-                          plan_cache=plevels[l].plans)
-        Acb = BlockMatrix([blk.prune(1e-14) for blk in Acb.blocks], cpart)
+        with span("amg.setup.interp", level=l, rows=n):
+            c_counts = [int((st == CPOINT).sum()) for st in status]
+            c_offsets = np.concatenate([[0], np.cumsum(c_counts)])[:-1]
+            cmap_parts = [np.cumsum(st == CPOINT) - 1 + c_offsets[d]
+                          for d, st in enumerate(status)]
+            P_blocks = []
+            for d, (lo, hi) in enumerate(ranges):
+                halo = Sb.blocks[d].offproc_columns(lo, hi, lo, hi)
+                row_status = np.full(n, FPOINT, dtype=np.int64)
+                row_status[lo:hi] = status[d]
+                col_status = np.full(n, FPOINT, dtype=np.int64)
+                col_status[lo:hi] = status[d]
+                col_status[halo] = _gather(status, part, halo)
+                col_cmap = np.zeros(n, dtype=np.int64)
+                col_cmap[lo:hi] = cmap_parts[d]
+                col_cmap[halo] = _gather(cmap_parts, part, halo)
+                P_blocks.append(direct_interpolation(
+                    Ab.blocks[d], Sb.blocks[d], row_status,
+                    col_status=col_status, cmap=col_cmap, nc=n_c))
+            Pb = BlockMatrix(P_blocks, part)
+        # -- Galerkin triple product: R by a transpose exchange, then the
+        #    two NAP matrix-row exchanges
+        with span("amg.setup.galerkin", level=l):
+            cpart = Partition.balanced(n_c, topo)
+            Rb = transpose_blocks(Pb, cpart)
+            APb = dist_spgemm(Ab, Pb, params=params, strategy=strategy,
+                              strategies=strategies, op="spgemm_AP",
+                              level=l, records=records,
+                              plan_cache=plevels[l].plans)
+            Acb = dist_spgemm(Rb, APb, params=params, strategy=strategy,
+                              strategies=strategies, op="spgemm_PtAP",
+                              level=l, records=records,
+                              plan_cache=plevels[l].plans)
+            Acb = BlockMatrix([blk.prune(1e-14) for blk in Acb.blocks], cpart)
         plevels[l].P, plevels[l].R, plevels[l].AP = Pb, Rb, APb
-        plevels[l].setup_seconds = time.perf_counter() - t0
         plevels.append(PartitionedLevel(A=Acb))
         # the stall check above guarantees 0 < n_c < n, so the Galerkin
         # coarse grid strictly shrinks — no host-style no-progress pop

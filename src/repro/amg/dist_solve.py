@@ -66,6 +66,7 @@ from .interpolation import estimate_rho_DinvA
 from .smoothers import chebyshev_coeffs, chebyshev_recurrence
 from .solve import (CYCLE_CHILDREN, MultiSolveResult, SolveOptions,
                     SolveResult, level_visits)
+from .spans import span
 
 DEV_AXES = ("pod", "lane")
 SOLVE_STRATEGIES = ("standard", "nap2", "nap3")
@@ -135,6 +136,32 @@ class DistLevel:
         return out
 
 
+def _dinv_blocks(A, part: Partition) -> np.ndarray:
+    """[D, rows_local] D⁻¹ of ``A`` in ``part``'s device layout (an empty
+    diagonal reads 1; padded rows 0)."""
+    d = A.diagonal()
+    dinv = 1.0 / np.where(d == 0, 1.0, d)
+    out = np.zeros((part.topo.n_procs, part.max_local_size))
+    for q in range(part.topo.n_procs):
+        lo, hi = part.local_range(q)
+        out[q, : hi - lo] = dinv[lo:hi]
+    return out
+
+
+def _coarse_inverse(A, part: Partition) -> np.ndarray:
+    """[D, m, D·m] row blocks of the coarsest ``A``'s dense pseudo-inverse,
+    columns in the all-gathered layout of the distributed direct solve."""
+    pinv = np.linalg.pinv(A.to_dense())
+    D, m = part.topo.n_procs, part.max_local_size
+    cinv = np.zeros((D, m, D * m))
+    for q in range(D):
+        lo, hi = part.local_range(q)
+        for e in range(D):
+            elo, ehi = part.local_range(e)
+            cinv[q, : hi - lo, e * m: e * m + ehi - elo] = pinv[lo:hi, elo:ehi]
+    return cinv
+
+
 class DistHierarchy:
     """An AMG hierarchy lowered onto a (pods × lanes) device mesh.
 
@@ -177,8 +204,9 @@ class DistHierarchy:
         self._dev_spec = spec
         self._sharding = sharding
         # level arrays, transferred (and sharded) once at build time
-        self._arrs = jax.device_put(
-            [self._level_arrays(lv) for lv in levels], sharding)
+        with span("amg.lower.place"):
+            self._arrs = jax.device_put(
+                [self._level_arrays(lv) for lv in levels], sharding)
 
     # ------------------------------------------------------------------ build
     @classmethod
@@ -293,76 +321,68 @@ class DistHierarchy:
         levels: list[DistLevel] = []
         for l, lv in enumerate(src_levels):
             part = parts[l]
-            gA = rect_vector_graph(lv.A, part, part)
-            compA = onoff_compute(lv.A, part, part)
-            sA, tA, cA = choose(gA, "spmv_A", compA)
-            Aop = make_op(lv.A, sA, part, part, gA)
-            # per-level local-kernel layout: ELL gather vs MXU-blocked BCSR
-            # (A only — P/R are too rectangular/scattered to block well, and
-            # the coarsest A never runs a SpMV, its solve being dense)
-            sel = select_dist_kernel(Aop.ell_cols)
-            if sel["kernel"] == "bcsr" and l + 1 < len(src_levels):
-                Aop.lower_bcsr(sel["block_size"])
-            else:
-                sel = dict(sel, kernel="ell", block_size=0)
-            d = lv.A.diagonal()
-            dinv = 1.0 / np.where(d == 0, 1.0, d)
-            dinv_dev = np.zeros((D, part.max_local_size), dtype=np.float64)
-            for q in range(D):
-                lo, hi = part.local_range(q)
-                dinv_dev[q, : hi - lo] = dinv[lo:hi]
-            dl = DistLevel(A=Aop, dinv=dinv_dev,
-                           strategies={"spmv_A": sA},
-                           modeled={"spmv_A": tA},
-                           local_kernel=sel)
-            dl.comm_stats["spmv_A"] = schedule_comm_stats(gA, sA)
-            nnz = Aop.onoff_nnz()
-            t_on, t_off = compA
-            t_comm = cA.get(sA, 0.0)
-            dl.onoff = {**nnz, "local_nnz": nnz["on_nnz"] + nnz["off_nnz"],
-                        "halo_empty": Aop.halo_empty,
-                        "t_on": t_on, "t_off": t_off, "t_comm": t_comm,
-                        "eff_modeled": overlap_efficiency(t_comm, t_on, t_off)}
-            if lv.P is not None and l + 1 < len(src_levels):
-                cpart = parts[l + 1]
-                gP = rect_vector_graph(lv.P, part, cpart)
-                sP, tP, _ = choose(gP, "interp",
-                                   onoff_compute(lv.P, part, cpart))
-                dl.P = make_op(lv.P, sP, part, cpart, gP)
-                gR = rect_vector_graph(lv.R, cpart, part)
-                sR, tR, _ = choose(gR, "restrict",
-                                   onoff_compute(lv.R, cpart, part))
-                dl.R = make_op(lv.R, sR, cpart, part, gR)
-                dl.rho = estimate_rho_DinvA(lv.A)
-                dl.strategies.update(interp=sP, restrict=sR)
-                dl.modeled.update(interp=tP, restrict=tR)
-                dl.comm_stats["interp"] = schedule_comm_stats(gP, sP)
-                dl.comm_stats["restrict"] = schedule_comm_stats(gR, sR)
-                # diagonal square blocks feed the block smoothers' dense
-                # factors (coarsest level never smooths — skip it there)
-                dl.local_A = [local_square_block(lv.A, part, q)
-                              for q in range(D)]
-            else:
-                if lv.P is not None:
-                    # a stall-pop in setup leaves a dangling P on the last
-                    # level; its A is by construction too large to treat as
-                    # the coarsest grid, so fail loudly rather than dense-
-                    # solving it
-                    raise ValueError(
-                        f"level {l} has P but no coarser level (coarsening "
-                        f"stalled); refusing the dense coarse solve at "
-                        f"n={lv.A.nrows}")
-                # coarsest: distributed dense pseudo-inverse solve
-                pinv = np.linalg.pinv(lv.A.to_dense())
-                m = part.max_local_size
-                cinv = np.zeros((D, m, D * m), dtype=np.float64)
-                for q in range(D):
-                    lo, hi = part.local_range(q)
-                    for e in range(D):
-                        elo, ehi = part.local_range(e)
-                        cinv[q, : hi - lo, e * m: e * m + ehi - elo] = \
-                            pinv[lo:hi, elo:ehi]
-                dl.coarse_inv = cinv
+            has_coarser = lv.P is not None and l + 1 < len(src_levels)
+            if lv.P is not None and not has_coarser:
+                # a stall-pop in setup leaves a dangling P on the last
+                # level; its A is by construction too large to treat as the
+                # coarsest grid, so fail loudly rather than dense-solving it
+                raise ValueError(
+                    f"level {l} has P but no coarser level (coarsening "
+                    f"stalled); refusing the dense coarse solve at "
+                    f"n={lv.A.nrows}")
+            with span("amg.lower.plan", level=l):
+                gA = rect_vector_graph(lv.A, part, part)
+                compA = onoff_compute(lv.A, part, part)
+                sA, tA, cA = choose(gA, "spmv_A", compA)
+                Aop = make_op(lv.A, sA, part, part, gA)
+                # per-level local-kernel layout: ELL gather vs MXU-blocked
+                # BCSR (A only — P/R are too rectangular/scattered to block
+                # well, and the coarsest A never runs a SpMV, its solve
+                # being dense)
+                sel = select_dist_kernel(Aop.ell_cols)
+                if sel["kernel"] == "bcsr" and l + 1 < len(src_levels):
+                    Aop.lower_bcsr(sel["block_size"])
+                else:
+                    sel = dict(sel, kernel="ell", block_size=0)
+                chosen, modeled = {"spmv_A": sA}, {"spmv_A": tA}
+                comm_stats = {"spmv_A": schedule_comm_stats(gA, sA)}
+                nnz = Aop.onoff_nnz()
+                t_on, t_off = compA
+                t_comm = cA.get(sA, 0.0)
+                onoff = {**nnz, "local_nnz": nnz["on_nnz"] + nnz["off_nnz"],
+                         "halo_empty": Aop.halo_empty,
+                         "t_on": t_on, "t_off": t_off, "t_comm": t_comm,
+                         "eff_modeled": overlap_efficiency(t_comm, t_on,
+                                                           t_off)}
+                Pop = Rop = None
+                if has_coarser:
+                    cpart = parts[l + 1]
+                    gP = rect_vector_graph(lv.P, part, cpart)
+                    sP, tP, _ = choose(gP, "interp",
+                                       onoff_compute(lv.P, part, cpart))
+                    Pop = make_op(lv.P, sP, part, cpart, gP)
+                    gR = rect_vector_graph(lv.R, cpart, part)
+                    sR, tR, _ = choose(gR, "restrict",
+                                       onoff_compute(lv.R, cpart, part))
+                    Rop = make_op(lv.R, sR, cpart, part, gR)
+                    chosen.update(interp=sP, restrict=sR)
+                    modeled.update(interp=tP, restrict=tR)
+                    comm_stats["interp"] = schedule_comm_stats(gP, sP)
+                    comm_stats["restrict"] = schedule_comm_stats(gR, sR)
+            with span("amg.lower.factors", level=l):
+                dl = DistLevel(A=Aop, dinv=_dinv_blocks(lv.A, part), P=Pop,
+                               R=Rop, strategies=chosen, modeled=modeled,
+                               local_kernel=sel, comm_stats=comm_stats,
+                               onoff=onoff)
+                if has_coarser:
+                    dl.rho = estimate_rho_DinvA(lv.A)
+                    # diagonal square blocks feed the block smoothers' dense
+                    # factors (the coarsest level never smooths)
+                    dl.local_A = [local_square_block(lv.A, part, q)
+                                  for q in range(D)]
+                else:
+                    # coarsest: distributed dense pseudo-inverse solve
+                    dl.coarse_inv = _coarse_inverse(lv.A, part)
             levels.append(dl)
         return levels
 
@@ -442,13 +462,7 @@ class DistHierarchy:
         for lv, dl in zip(src_levels, self.levels):
             part = dl.A.row_part
             dl.A.refresh_values(block_of(lv.A))
-            d = lv.A.diagonal()
-            dinv = 1.0 / np.where(d == 0, 1.0, d)
-            dinv_dev = np.zeros((D, part.max_local_size), dtype=np.float64)
-            for q in range(D):
-                lo, hi = part.local_range(q)
-                dinv_dev[q, : hi - lo] = dinv[lo:hi]
-            dl.dinv = dinv_dev
+            dl.dinv = _dinv_blocks(lv.A, part)
             if dl.P is not None:
                 dl.P.refresh_values(block_of(lv.P))
                 dl.R.refresh_values(block_of(lv.R))
@@ -457,16 +471,7 @@ class DistHierarchy:
                               for q in range(D)]
                 dl._minv_cache.clear()
             else:
-                pinv = np.linalg.pinv(lv.A.to_dense())
-                m = part.max_local_size
-                cinv = np.zeros((D, m, D * m), dtype=np.float64)
-                for q in range(D):
-                    lo, hi = part.local_range(q)
-                    for e in range(D):
-                        elo, ehi = part.local_range(e)
-                        cinv[q, : hi - lo, e * m: e * m + ehi - elo] = \
-                            pinv[lo:hi, elo:ehi]
-                dl.coarse_inv = cinv
+                dl.coarse_inv = _coarse_inverse(lv.A, part)
         placed = jax.device_put(
             [self._level_arrays(dl) for dl in self.levels], self._sharding)
         for old, new in zip(self._arrs, placed):
@@ -503,23 +508,22 @@ class DistHierarchy:
     def _spmv(self, op: DistOperator, arrs: dict, x):
         return op.apply(arrs, x, overlap=self.overlap)
 
-    def _pdot(self, a, b):
-        part = jnp.sum(a * b)
+    def _psum(self, part):
         if self.reduce_strategy == "flat":
             # scalar all-reduce: flat is the REDUCE_SIGNATURES["flat"]
             # baseline the hierarchical strategy is measured against
             return jax.lax.psum(part, DEV_AXES)  # comm-audit: allow flat-psum
         return hier_psum(part, *DEV_AXES, strategy=self.reduce_strategy)
 
-    def _pnorm(self, r):
-        return jnp.sqrt(self._pdot(r, r))
+    def _pdot(self, a, b, axis=None):
+        """Global dot, replicated; ``axis=0`` gives the per-column dots
+        [k] of [local, k] operands."""
+        with jax.named_scope("pcg.dot"):
+            return self._psum(jnp.sum(a * b, axis=axis))
 
-    def _pdot_cols(self, a, b):
-        """Per-column dot for [local, k] operands → replicated [k]."""
-        part = jnp.sum(a * b, axis=0)
-        if self.reduce_strategy == "flat":
-            return jax.lax.psum(part, DEV_AXES)  # comm-audit: allow flat-psum
-        return hier_psum(part, *DEV_AXES, strategy=self.reduce_strategy)
+    def _pnorm(self, r, axis=None):
+        with jax.named_scope("pcg.dot"):
+            return jnp.sqrt(self._psum(jnp.sum(r * r, axis=axis)))
 
     def _relax(self, dl: DistLevel, arrs: dict, x, b, opts, sweeps: int):
         if sweeps == 0:
@@ -566,19 +570,28 @@ class DistHierarchy:
         shape = shape or opts.cycle
         dl = self.levels[level]
         a = arrs[level]
+        tag = f"L{level}."                            # the ops' scope names
         if dl.coarse_inv is not None:                 # coarsest: direct solve
-            full = hier_all_gather(b, *DEV_AXES)      # [D * rows_local]
-            return a["cinv"] @ full
-        if x is None:
-            x = jnp.zeros_like(b)
-        x = self._relax(dl, a, x, b, opts, opts.presweeps)
-        r = b - self._spmv(dl.A, a["A"], x)
-        rc = self._spmv(dl.R, a["R"], r)
+            with jax.named_scope(tag + "coarse"):
+                full = hier_all_gather(b, *DEV_AXES)  # [D * rows_local]
+                return a["cinv"] @ full
+        with jax.named_scope(tag + "presmooth"):
+            if x is None:
+                x = jnp.zeros_like(b)
+            x = self._relax(dl, a, x, b, opts, opts.presweeps)
+        with jax.named_scope(tag + "residual"):
+            r = b - self._spmv(dl.A, a["A"], x)
+        with jax.named_scope(tag + "restrict"):
+            rc = self._spmv(dl.R, a["R"], r)
         ec = None
-        for child in CYCLE_CHILDREN[shape]:           # coarse-grid solve(s)
+        # the coarse-grid solve(s) stay outside this level's phase scopes,
+        # so an op carries exactly one level's name
+        for child in CYCLE_CHILDREN[shape]:
             ec = self._cycle_dev(arrs, rc, ec, opts, level + 1, shape=child)
-        x = x + self._spmv(dl.P, a["P"], ec)
-        x = self._relax(dl, a, x, b, opts, opts.postsweeps)
+        with jax.named_scope(tag + "interp"):
+            x = x + self._spmv(dl.P, a["P"], ec)
+        with jax.named_scope(tag + "postsmooth"):
+            x = self._relax(dl, a, x, b, opts, opts.postsweeps)
         return x
 
     # ------------------------------------------------------------- programs
@@ -682,27 +695,28 @@ class DistHierarchy:
                 lambda bc, xc: self._cycle_dev(arrs, bc, xc, opts),
                 in_axes=1, out_axes=1)(b, x)
 
+        def residual0(arrs, x, b, apply=spmv0):     # b − A·x on level 0
+            with jax.named_scope("L0.residual"):
+                return b - apply(arrs, x)
+
         def resid_norm_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            r = b - spmv0(arrs, x)
-            return self._pnorm(r)
+            return self._pnorm(residual0(arrs, x, b))
 
         def resid_norm_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            r = b - spmv0_m(arrs, x)
-            return jnp.sqrt(self._pdot_cols(r, r))
+            return self._pnorm(residual0(arrs, x, b, spmv0_m), axis=0)
 
         def cycle_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
             x = self._cycle_dev(arrs, b, x, opts)
-            r = b - spmv0(arrs, x)
-            return x[None], self._pnorm(r)
+            return x[None], self._pnorm(residual0(arrs, x, b))
 
         def cycle_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
             x = vcycle_m(arrs, b, x)
-            r = b - spmv0_m(arrs, x)
-            return x[None], jnp.sqrt(self._pdot_cols(r, r))
+            return x[None], self._pnorm(residual0(arrs, x, b, spmv0_m),
+                                        axis=0)
 
         def vcycle_body(b, arrs):
             b, arrs = b[0], squeeze(arrs)
@@ -714,46 +728,53 @@ class DistHierarchy:
 
         def pcg_init_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            r = b - spmv0(arrs, x)                  # x0 warm start
+            r = residual0(arrs, x, b)               # x0 warm start
             z = self._cycle_dev(arrs, r, None, opts)
             rz = self._pdot(r, z)
             return r[None], z[None], rz, self._pnorm(r)
 
         def pcg_init_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            r = b - spmv0_m(arrs, x)
+            r = residual0(arrs, x, b, spmv0_m)
             z = vcycle_m(arrs, r, None)
-            rz = self._pdot_cols(r, z)
-            return r[None], z[None], rz, jnp.sqrt(self._pdot_cols(r, r))
+            rz = self._pdot(r, z, axis=0)
+            return r[None], z[None], rz, self._pnorm(r, axis=0)
 
         def pcg_step_body(x, r, p, rz, arrs):
             x, r, p = x[0], r[0], p[0]
             arrs = squeeze(arrs)
-            Ap = spmv0(arrs, p)
-            alpha = rz / self._pdot(p, Ap)
-            x = x + alpha * p
-            r = r - alpha * Ap
+            with jax.named_scope("L0.Ap"):
+                Ap = spmv0(arrs, p)
+            pAp = self._pdot(p, Ap)
+            with jax.named_scope("pcg.update"):
+                alpha = rz / pAp
+                x = x + alpha * p
+                r = r - alpha * Ap
             rnorm = self._pnorm(r)
             z = self._cycle_dev(arrs, r, None, opts)
             rz_new = self._pdot(r, z)
-            p = z + (rz_new / rz) * p
+            with jax.named_scope("pcg.update"):
+                p = z + (rz_new / rz) * p
             return x[None], r[None], p[None], rz_new, rnorm
 
         def pcg_step_m_body(x, r, p, rz, arrs):
             x, r, p = x[0], r[0], p[0]              # [local, k]; rz [k]
             arrs = squeeze(arrs)
-            Ap = spmv0_m(arrs, p)
-            # columns that already converged exactly (rz = pAp = 0, e.g. a
-            # zero RHS) must not poison the batch with 0/0 NaNs: guard the
-            # divisions so such columns step by exactly zero
-            den = self._pdot_cols(p, Ap)
-            alpha = rz / jnp.where(den == 0, 1.0, den)  # [k], bcasts on cols
-            x = x + alpha * p
-            r = r - alpha * Ap
-            rnorm = jnp.sqrt(self._pdot_cols(r, r))
+            with jax.named_scope("L0.Ap"):
+                Ap = spmv0_m(arrs, p)
+            den = self._pdot(p, Ap, axis=0)
+            with jax.named_scope("pcg.update"):
+                # columns that already converged exactly (rz = pAp = 0,
+                # e.g. a zero RHS) must not poison the batch with 0/0 NaNs:
+                # guard the divisions so such columns step by exactly zero
+                alpha = rz / jnp.where(den == 0, 1.0, den)  # [k], on cols
+                x = x + alpha * p
+                r = r - alpha * Ap
+            rnorm = self._pnorm(r, axis=0)
             z = vcycle_m(arrs, r, None)
-            rz_new = self._pdot_cols(r, z)
-            p = z + (rz_new / jnp.where(rz == 0, 1.0, rz)) * p
+            rz_new = self._pdot(r, z, axis=0)
+            with jax.named_scope("pcg.update"):
+                p = z + (rz_new / jnp.where(rz == 0, 1.0, rz)) * p
             return x[None], r[None], p[None], rz_new, rnorm
 
         progs = {
@@ -997,15 +1018,15 @@ def dist_vcycle(dh: DistHierarchy, b: np.ndarray, opts=None) -> np.ndarray:
     return dh.gather(prog(bd, arrs))
 
 
-def _column_results(dh, x, res, nb, tol):
-    """Slice a batched solve into per-column SolveResults.
+def _column_results(X, res, nb, tol):
+    """Slice a batched solve's gathered answer ``X`` [n, k] into per-column
+    SolveResults.
 
     Matches the host backend's per-column semantics: each column reports
     the iteration count at which IT first converged (the batch may have
     kept cycling for slower columns) and a residual history truncated
     there, so ``iterations``/``avg_conv_factor`` agree across backends.
     """
-    X = dh.gather(x)
     k = X.shape[1]
     cols = []
     for j in range(k):
@@ -1042,7 +1063,7 @@ def dist_solve(dh: DistHierarchy, b: np.ndarray, tol: float = 1e-8,
                 break
             x, rn = progs["cycle_m"](x, bd, arrs)
             res.append(np.asarray(rn, dtype=np.float64))
-        return _column_results(dh, x, res, nb, tol)
+        return _column_results(dh.gather(x), res, nb, tol)
     nb = float(np.linalg.norm(b)) or 1.0
     res = [float(progs["resid_norm"](x, bd, arrs))]
     for it in range(maxiter):
@@ -1058,30 +1079,41 @@ def dist_pcg(dh: DistHierarchy, b: np.ndarray, tol: float = 1e-8,
     """AMG-preconditioned CG, preconditioner + operator fully on device.
 
     Supports ``x0=`` warm starts and multi-RHS ``b`` of shape ``[n, k]``.
+    The call is one ``amg.pcg`` span holding the host's staging
+    (``amg.pcg.scatter`` / ``amg.pcg.gather``), each program's dispatch
+    (``amg.pcg.init`` / ``amg.pcg.step``) and each wait for the residual
+    norm the convergence check reads (``amg.pcg.sync``).
     """
     opts = opts or SolveOptions()
     b = np.asarray(b)  # staged by BoundSolver._check_b; keep dtype
     multi = b.ndim == 2
-    progs, arrs = dh.programs(opts)
-    bd = dh.scatter(b)
-    x = dh.scatter(np.zeros_like(b) if x0 is None else np.asarray(x0))
-    suffix = "_m" if multi else ""
-    r, z, rz, rnorm = progs["pcg_init" + suffix](x, bd, arrs)
-    p = z
+    with span("amg.pcg", n=b.shape[0], columns=b.shape[1] if multi else 1,
+              maxiter=maxiter):
+        progs, arrs = dh.programs(opts)
+        x0 = np.zeros_like(b) if x0 is None else np.asarray(x0)
+        with span("amg.pcg.scatter", bytes=b.nbytes):
+            bd = dh.scatter(b)
+        with span("amg.pcg.scatter", bytes=x0.nbytes):
+            x = dh.scatter(x0)
+        suffix = "_m" if multi else ""
+        with span("amg.pcg.init"):
+            r, z, rz, rnorm = progs["pcg_init" + suffix](x, bd, arrs)
+        p = z
+        nb = _norms(b) if multi else (float(np.linalg.norm(b)) or 1.0)
+        with span("amg.pcg.sync"):
+            res = [np.asarray(rnorm, dtype=np.float64) if multi
+                   else float(rnorm)]
+        it = 0
+        while it < maxiter and not np.all(res[-1] / nb < tol):
+            with span("amg.pcg.step"):
+                x, r, p, rz, rnorm = progs["pcg_step" + suffix](x, r, p, rz,
+                                                                arrs)
+            with span("amg.pcg.sync"):
+                res.append(np.asarray(rnorm, dtype=np.float64) if multi
+                           else float(rnorm))
+            it += 1
+        with span("amg.pcg.gather", bytes=x.nbytes):
+            X = dh.gather(x)
     if multi:
-        nb = _norms(b)
-        res = [np.asarray(rnorm, dtype=np.float64)]
-        for _ in range(maxiter):
-            if (res[-1] / nb < tol).all():
-                break
-            x, r, p, rz, rnorm = progs["pcg_step_m"](x, r, p, rz, arrs)
-            res.append(np.asarray(rnorm, dtype=np.float64))
-        return _column_results(dh, x, res, nb, tol)
-    nb = float(np.linalg.norm(b)) or 1.0
-    res = [float(rnorm)]
-    for it in range(maxiter):
-        if res[-1] / nb < tol:
-            return SolveResult(dh.gather(x), res, it, True)
-        x, r, p, rz, rnorm = progs["pcg_step"](x, r, p, rz, arrs)
-        res.append(float(rnorm))
-    return SolveResult(dh.gather(x), res, maxiter, res[-1] / nb < tol)
+        return _column_results(X, res, nb, tol)
+    return SolveResult(X, res, it, bool(res[-1] / nb < tol))

@@ -267,24 +267,33 @@ class DistOperator:
         original fused serial form (``halo_exchange → A·[x|halo]``) as the
         parity oracle.  Levels whose plan moves zero entries emit no
         collective at all in either mode.
+
+        The ops carry the scope ``halo`` (the exchange with its packing),
+        ``local`` (``A_on·x``; the whole fused product in the serial form)
+        or ``remote`` (``A_off·halo``).
         """
         if self.halo_empty:
-            return self._on_product(arrs, x_loc)
+            with jax.named_scope("local"):
+                return self._on_product(arrs, x_loc)
         psel = None if self.plan.pool_sel is None else arrs["psel"]
-        if overlap:
-            # issue the exchange first: `halo` is not consumed until the
-            # off-process correction, so the collective and the on-process
-            # product are dataflow-independent and free to overlap.
+        # the exchange goes first either way; with overlap, `halo` is
+        # not consumed until the off-process correction, so the collective
+        # and the on-process product are dataflow-independent and free to
+        # overlap
+        with jax.named_scope("halo"):
             halo = halo_exchange(x_loc, self.plan, arrs["send"],
                                  arrs["recv"], psel)
-            y = self._on_product(arrs, x_loc)
-            return y + ell_apply(arrs["off_cols"], arrs["off_vals"], halo)
-        halo = halo_exchange(x_loc, self.plan, arrs["send"], arrs["recv"], psel)
-        xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
-        if "bcols" in arrs:
-            y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
-            return y[: self.rows_local]
-        return ell_apply(arrs["cols"], arrs["vals"], xfull)
+        if overlap:
+            with jax.named_scope("local"):
+                y = self._on_product(arrs, x_loc)
+            with jax.named_scope("remote"):
+                return y + ell_apply(arrs["off_cols"], arrs["off_vals"], halo)
+        with jax.named_scope("local"):
+            xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
+            if "bcols" in arrs:
+                y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
+                return y[: self.rows_local]
+            return ell_apply(arrs["cols"], arrs["vals"], xfull)
 
     # ------------------------------------------------------- host-side layout
     def scatter_x(self, x: np.ndarray, dtype=None) -> np.ndarray:

@@ -2,13 +2,13 @@
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
 from .csr import CSR
 from .interpolation import (direct_interpolation, jacobi_smooth_prolongator,
                             tentative_prolongator)
+from .spans import span
 from .splitting import mis2_aggregation, pmis
 from .strength import classical_strength, symmetric_strength
 
@@ -19,7 +19,6 @@ class Level:
     P: CSR | None = None        # to the NEXT (coarser) level
     R: CSR | None = None        # restriction = Pᵀ
     AP: CSR | None = None       # intermediate Galerkin product (Fig. 21 op)
-    setup_seconds: float = 0.0
     # per-level smoother data extracted once and carried on the level
     # (block-Jacobi diagonal-block inverses, keyed by (kind, block_size,
     # parts)) — the setup-phase half of the block smoothers
@@ -112,13 +111,17 @@ def interpolation_stage(A: CSR, S: CSR, split: np.ndarray, solver: str = "rs",
 
 def coarsen_level(A: CSR, solver: str = "rs", theta: float = 0.25,
                   aggressive: bool = False, prolongation_sweeps: int = 1,
-                  seed: int = 42) -> CSR | None:
-    """strength → splitting → interpolation; ``None`` when coarsening stalls."""
-    S = strength_stage(A, solver, theta)
-    split = splitting_stage(S, solver, seed=seed, aggressive=aggressive)
+                  seed: int = 42, level: int = 0) -> CSR | None:
+    """strength → splitting → interpolation; ``None`` when coarsening stalls.
+    Each stage is an ``amg.setup.*`` span of ``level``."""
+    with span("amg.setup.strength", level=level, rows=A.nrows):
+        S = strength_stage(A, solver, theta)
+    with span("amg.setup.splitting", level=level, rows=A.nrows):
+        split = splitting_stage(S, solver, seed=seed, aggressive=aggressive)
     if splitting_stalled(split, A.nrows, solver):
         return None
-    return interpolation_stage(A, S, split, solver, prolongation_sweeps)
+    with span("amg.setup.interp", level=level, rows=A.nrows):
+        return interpolation_stage(A, S, split, solver, prolongation_sweeps)
 
 
 def project_pattern_values(src: CSR, indptr: np.ndarray,
@@ -186,18 +189,17 @@ def setup(A: CSR, solver: str = "rs", theta: float = 0.25,
     levels = [Level(A=A)]
     l = 0
     while levels[l].A.nrows > max_coarse and l + 1 < max_levels:
-        t0 = time.perf_counter()
         Al = levels[l].A
         P = coarsen_level(Al, solver, theta, aggressive,
-                          prolongation_sweeps, seed + l)
+                          prolongation_sweeps, seed + l, level=l)
         if P is None:
             break  # coarsening stalled
-        R = P.T
-        AP = Al.spgemm(P)                                        # Galerkin 1/2
-        Ac = R.spgemm(AP)                                        # Galerkin 2/2
-        Ac = Ac.prune(1e-14)
+        with span("amg.setup.galerkin", level=l):
+            R = P.T
+            AP = Al.spgemm(P)                                    # Galerkin 1/2
+            Ac = R.spgemm(AP)                                    # Galerkin 2/2
+            Ac = Ac.prune(1e-14)
         levels[l].P, levels[l].R, levels[l].AP = P, R, AP
-        levels[l].setup_seconds = time.perf_counter() - t0
         levels.append(Level(A=Ac))
         if Ac.nrows >= Al.nrows:  # no progress
             levels.pop()

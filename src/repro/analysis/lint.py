@@ -18,9 +18,11 @@
     (e.g. a done-callback) resets the scope.
 
 ``traced-host-call``
-    No wall-clock reads or host callbacks inside functions handed to
-    ``jax.jit`` / ``shard_map`` / ``vmap`` — they would be baked in at trace
-    time (or stall the device stream), silently corrupting measurements.
+    No wall-clock reads, host spans (``span`` of ``repro.amg.spans``) or
+    host callbacks inside functions handed to ``jax.jit`` / ``shard_map`` /
+    ``vmap`` — they would be baked in at trace time, timing the trace and
+    not the run (or stall the device stream), silently corrupting
+    measurements.
 
 ``frozen-mutation``
     No attribute assignment on frozen-dataclass instances and no
@@ -51,6 +53,7 @@ BLOCKING_METHODS = frozenset({"result", "update_wire", "drain"})
 TRACE_WRAPPERS = frozenset({"jit", "shard_map", "smap", "vmap", "pmap"})
 HOST_CALLS = frozenset({
     "time.time", "time.perf_counter", "time.monotonic",
+    "time.time_ns", "time.perf_counter_ns",
     "datetime.now", "datetime.datetime.now", "datetime.utcnow",
     "jax.pure_callback", "jax.experimental.io_callback", "io_callback",
     "jax.debug.callback",
@@ -118,6 +121,7 @@ class _Linter(ast.NodeVisitor):
         self.violations: list[LintViolation] = []
         self._fn_stack: list[str] = []      # "async" | "sync"
         self._traced_names: set[str] = set()
+        self._span_calls: set[str] = set()  # how this module names span()
         self._traced_depth = 0
         self._frozen_vars: list[set[str]] = [set()]
         self._in_post_init = False
@@ -168,9 +172,23 @@ class _Linter(ast.NodeVisitor):
         self._visit_fn(node, "async")
 
     def visit_Module(self, node: ast.Module) -> None:
-        # pre-scan: local functions handed to jit/shard_map/vmap are traced
+        # pre-scan: local functions handed to jit/shard_map/vmap are traced;
+        # imports of the host span (`from .spans import span`, `from
+        # repro.amg import spans`, `import repro.amg.spans as s`)
         for sub in ast.walk(node):
-            if isinstance(sub, ast.Call):
+            if isinstance(sub, ast.ImportFrom):
+                from_spans = (sub.module or "").rsplit(".", 1)[-1] == "spans"
+                for alias in sub.names:
+                    if from_spans and alias.name == "span":
+                        self._span_calls.add(alias.asname or "span")
+                    elif alias.name == "spans":
+                        self._span_calls.add(f"{alias.asname or 'spans'}.span")
+            elif isinstance(sub, ast.Import):
+                for alias in sub.names:
+                    if alias.name.rsplit(".", 1)[-1] == "spans":
+                        self._span_calls.add(
+                            f"{alias.asname or alias.name}.span")
+            elif isinstance(sub, ast.Call):
                 name = _dotted(sub.func)
                 if name and name.rsplit(".", 1)[-1] in TRACE_WRAPPERS:
                     for arg in sub.args:
@@ -210,7 +228,7 @@ class _Linter(ast.NodeVisitor):
                            "`await asyncio.sleep`")
 
         if self._traced_depth > 0 and (
-                name in HOST_CALLS
+                name in HOST_CALLS or name in self._span_calls
                 or leaf in {"pure_callback", "io_callback"}
                 or name.endswith("debug.callback")):
             self._flag("traced-host-call", node,

@@ -286,6 +286,35 @@ def test_lint_traced_host_call():
     assert [v.rule for v in decorated] == ["traced-host-call"]
 
 
+def test_lint_traced_host_span():
+    """A host span or a nanosecond clock inside a traced body would time
+    the trace, not the run: flagged under every import form of
+    ``repro.amg.spans``; the same calls on the host are fine."""
+    vs = _lint("""
+        import time
+        import jax
+        import repro.amg.spans as sp
+        from repro.amg import spans
+        from repro.amg.spans import span
+        from .spans import span as host_span
+
+        def body(x):
+            with span("a"):
+                x = x + 1
+            with spans.span("b"), sp.span("c"), host_span("d"):
+                x = x * 2
+            return x + time.perf_counter_ns() + time.time_ns()
+
+        prog = jax.jit(body)
+
+        def host_side():                        # not traced: fine
+            with span("e"), spans.span("f"):
+                return time.perf_counter_ns()
+        """)
+    assert [v.rule for v in vs] == ["traced-host-call"] * 6, vs
+    assert sorted(v.line for v in vs) == [10, 12, 12, 12, 14, 14]
+
+
 def test_lint_frozen_mutation():
     vs = _lint("""
         import dataclasses
