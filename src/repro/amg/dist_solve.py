@@ -58,7 +58,7 @@ from ..core.perf_model import (TPU_V5E, MachineParams, overlap_efficiency,
 from ..core.selector import select
 from ..core.topology import Partition, Topology
 from .dist import rect_vector_graph, schedule_comm_stats
-from ..kernels.spmv.ops import select_dist_kernel
+from ..kernels.spmv.ops import select_dia, select_dist_kernel
 from .dist_spmv import (DistOperator, build_dist_operator,
                         build_dist_operator_from_blocks, local_square_block)
 from .hierarchy import Hierarchy
@@ -335,18 +335,17 @@ class DistHierarchy:
                 compA = onoff_compute(lv.A, part, part)
                 sA, tA, cA = choose(gA, "spmv_A", compA)
                 Aop = make_op(lv.A, sA, part, part, gA)
-                # per-level local-kernel layout: ELL gather vs MXU-blocked
-                # BCSR (A only — P/R are too rectangular/scattered to block
-                # well, and the coarsest A never runs a SpMV, its solve
-                # being dense)
-                sel = select_dist_kernel(Aop.ell_cols)
-                if sel["kernel"] == "bcsr" and l + 1 < len(src_levels):
-                    Aop.lower_bcsr(sel["block_size"])
-                else:
-                    sel = dict(sel, kernel="ell", block_size=0)
+                nnz = Aop.onoff_nnz()
+                with span("amg.lower.layout", level=l) as layout:
+                    sel = cls._lower_layout(Aop, l + 1 < len(src_levels))
+                    dia = Aop.dia_offsets is not None
+                    layout.update(
+                        layout=Aop.local_kernel,
+                        diagonals=len(Aop.dia_offsets) if dia else 0,
+                        nnz=nnz["on_nnz"] + nnz["off_nnz"],
+                        dia_nnz=nnz["on_nnz"] if dia else 0)
                 chosen, modeled = {"spmv_A": sA}, {"spmv_A": tA}
                 comm_stats = {"spmv_A": schedule_comm_stats(gA, sA)}
-                nnz = Aop.onoff_nnz()
                 t_on, t_off = compA
                 t_comm = cA.get(sA, 0.0)
                 onoff = {**nnz, "local_nnz": nnz["on_nnz"] + nnz["off_nnz"],
@@ -386,6 +385,31 @@ class DistHierarchy:
             levels.append(dl)
         return levels
 
+    @staticmethod
+    def _lower_layout(Aop: DistOperator, smooths: bool) -> dict:
+        """Choose and lower ``A``'s local-product layout; returns the
+        :func:`~repro.kernels.spmv.ops.select_dist_kernel` dict with the
+        layout taken.
+
+        On a level that smooths (A only — P/R are too rectangular or
+        scattered, and the coarsest A never runs a SpMV, its solve being
+        dense): MXU-blocked BCSR where the heuristic picks it; else DIA
+        for the on-part when its distinct offsets ``col − row`` number no
+        more than its ELL width (:func:`~repro.kernels.spmv.ops.select_dia`);
+        else ELL.
+        """
+        sel = select_dist_kernel(Aop.ell_cols)
+        if not smooths:
+            return dict(sel, kernel="ell", block_size=0)
+        if sel["kernel"] == "bcsr":
+            Aop.lower_bcsr(sel["block_size"])
+            return sel
+        offsets = select_dia(Aop.on_cols)
+        if offsets is not None:
+            Aop.lower_dia(offsets)
+            return dict(sel, kernel="dia", block_size=0)
+        return dict(sel, kernel="ell", block_size=0)
+
     # ------------------------------------------------------------- reporting
     def selection_table(self) -> list[dict]:
         """One row per (level, op): chosen strategy + modeled seconds."""
@@ -422,6 +446,7 @@ class DistHierarchy:
                 "level": l,
                 "kernel": dl.A.local_kernel,
                 "block_size": dl.A.block_size,
+                "diagonals": len(dl.A.dia_offsets or ()),
                 "rows_local": dl.A.rows_local,
                 "ell_fill": sel.get("ell_fill", 0.0),
                 "bcsr_fill": sel.get("bcsr_fill", 0.0),

@@ -19,7 +19,9 @@ strategy (see :mod:`repro.amg.dist_solve`).
 Execute (device, every smoother sweep / residual / restrict / interpolate):
   shard_map body = halo_exchange → local product
   (:func:`repro.kernels.spmv.spmv.ell_apply`, or
-  :func:`repro.kernels.spmv.bcsr.bcsr_apply` on a BCSR-lowered level).
+  :func:`repro.kernels.spmv.bcsr.bcsr_apply` on a BCSR-lowered level, or
+  :func:`repro.kernels.spmv.dia.dia_apply` for a DIA-lowered on-process
+  part).
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ from ..core.nap_collectives import (HaloPlan, build_halo_plan, halo_exchange,
                                     halo_signature)
 from ..core.topology import Partition, Topology
 from ..kernels.spmv.bcsr import bcsr_apply
+from ..kernels.spmv.dia import LANES, dia_apply
 from ..kernels.spmv.spmv import ell_apply
 from .csr import CSR
 from .dist import rect_vector_graph
@@ -123,6 +126,10 @@ class DistOperator:
     bcsr_on_bcols: np.ndarray | None = None  # on-part lowering (A_off stays ELL)
     bcsr_on_bvals: np.ndarray | None = None
     block_size: int = 0                    # 0 = ELL layout
+    # optional DIA lowering of the on-part (see lower_dia): one value row
+    # per offset col − row, read as shifted slices of x
+    dia_offsets: tuple[int, ...] | None = None
+    dia_vals: np.ndarray | None = None     # [D, n_diag, nb, 128]
 
     @property
     def n_devices(self) -> int:
@@ -136,8 +143,11 @@ class DistOperator:
 
     @property
     def local_kernel(self) -> str:
-        """Layout label for reporting: 'bcsr' once lowered, else 'ell'."""
-        return "bcsr" if self.bcsr_bcols is not None else "ell"
+        """Layout label for reporting: 'bcsr' or 'dia' once lowered, else
+        'ell'."""
+        if self.bcsr_bcols is not None:
+            return "bcsr"
+        return "dia" if self.dia_vals is not None else "ell"
 
     @property
     def expected_signature(self) -> tuple[str, ...]:
@@ -160,8 +170,12 @@ class DistOperator:
         arrs = {"cols": self.ell_cols, "vals": self.ell_vals,
                 "send": self.send_idx, "recv": self.recv_sel,
                 "psel": self.pool_sel,
-                "on_cols": self.on_cols, "on_vals": self.on_vals,
                 "off_cols": self.off_cols, "off_vals": self.off_vals}
+        if self.dia_vals is not None:
+            arrs["dia"] = self.dia_vals
+        else:
+            arrs["on_cols"] = self.on_cols
+            arrs["on_vals"] = self.on_vals
         if self.bcsr_bcols is not None:
             arrs["bcols"] = self.bcsr_bcols
             arrs["bvals"] = self.bcsr_bvals
@@ -213,6 +227,34 @@ class DistOperator:
             self.on_cols, self.on_vals, self.plan.local_n)
         self.block_size = int(block_size)
 
+    def lower_dia(self, offsets: tuple[int, ...]) -> None:
+        """Lower the on-part to DIA on ``offsets`` (ascending, covering
+        every ``on_cols − row`` of every device; see
+        :func:`~repro.kernels.spmv.ops.select_dia`).
+
+        Once lowered, the halo-free product ``A_on·x`` runs as
+        :func:`~repro.kernels.spmv.dia.dia_apply`; the off-part stays ELL
+        and the fused arrays stay for the serial parity oracle.
+        """
+        D, R, _ = self.on_cols.shape
+        nb = -(-R // LANES)
+        slot = np.full(offsets[-1] - offsets[0] + 1, -1, dtype=np.int64)
+        slot[np.asarray(offsets) - offsets[0]] = np.arange(len(offsets))
+        rows = np.arange(R, dtype=self.on_cols.dtype)[:, None]
+        vals = np.zeros((D, len(offsets), nb * LANES),
+                        dtype=self.on_vals.dtype)
+        for d in range(D):
+            keep = self.on_cols[d] >= 0
+            diag = slot[(self.on_cols[d] - rows)[keep] - offsets[0]]
+            r = np.broadcast_to(rows, keep.shape)[keep]
+            # bincount sums a column stored twice in one row, as ELL does
+            vals[d] = np.bincount(
+                diag * (nb * LANES) + r, weights=self.on_vals[d][keep],
+                minlength=len(offsets) * nb * LANES).reshape(
+                    len(offsets), nb * LANES)
+        self.dia_offsets = tuple(int(o) for o in offsets)
+        self.dia_vals = vals.reshape(D, len(offsets), nb, LANES)
+
     def refresh_values(self, block_of) -> None:
         """Value-only re-lowering onto the frozen layouts.
 
@@ -222,7 +264,8 @@ class DistOperator:
         function of ``indptr``/``indices`` (see :func:`_ell_block`), so with
         a frozen pattern the column maps, halo plan and on/off split
         layouts are all reproduced exactly; only the value planes change.
-        BCSR lowerings are re-tiled at the same ``block_size``.
+        BCSR lowerings are re-tiled at the same ``block_size``, DIA
+        lowerings re-filled on the same offsets.
         """
         vals = np.zeros(self.ell_cols.shape, dtype=np.float64)
         for d in range(self.n_devices):
@@ -242,13 +285,25 @@ class DistOperator:
         self.off_cols, self.off_vals = off_cols, off_vals
         if self.block_size:
             self.lower_bcsr(self.block_size)
+        if self.dia_offsets is not None:
+            self.lower_dia(self.dia_offsets)
 
     def _on_product(self, arrs, x_loc):
         """``A_on · x`` — the halo-free product that overlaps the exchange."""
         if "on_bcols" in arrs:
             y = bcsr_apply(arrs["on_bcols"], arrs["on_bvals"], x_loc)
             return y[: self.rows_local]
+        if "dia" in arrs:
+            y = dia_apply(self.dia_offsets, arrs["dia"], x_loc)
+            return y[: self.rows_local]
         return ell_apply(arrs["on_cols"], arrs["on_vals"], x_loc)
+
+    def _fused_product(self, arrs, xfull):
+        """``A · [x | halo]`` in one product: the serial parity oracle."""
+        if "bcols" in arrs:
+            y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
+            return y[: self.rows_local]
+        return ell_apply(arrs["cols"], arrs["vals"], xfull)
 
     def apply(self, arrs: dict[str, jnp.ndarray], x_loc: jnp.ndarray,
               overlap: bool = True) -> jnp.ndarray:
@@ -264,9 +319,9 @@ class DistOperator:
         independent ``y_on = A_on·x`` product so XLA's async collectives can
         hide the NAP message latency behind the on-process SpMV; the
         ``A_off·halo`` correction lands after.  ``overlap=False`` keeps the
-        original fused serial form (``halo_exchange → A·[x|halo]``) as the
-        parity oracle.  Levels whose plan moves zero entries emit no
-        collective at all in either mode.
+        original fused serial form (``halo_exchange → A·[x|halo]``, ELL or
+        BCSR, never DIA) as the parity oracle.  Levels whose plan moves
+        zero entries emit no collective at all in either mode.
 
         The ops carry the scope ``halo`` (the exchange with its packing),
         ``local`` (``A_on·x``; the whole fused product in the serial form)
@@ -274,7 +329,9 @@ class DistOperator:
         """
         if self.halo_empty:
             with jax.named_scope("local"):
-                return self._on_product(arrs, x_loc)
+                if overlap:
+                    return self._on_product(arrs, x_loc)
+                return self._fused_product(arrs, x_loc)
         psel = None if self.plan.pool_sel is None else arrs["psel"]
         # the exchange goes first either way; with overlap, `halo` is
         # not consumed until the off-process correction, so the collective
@@ -290,10 +347,7 @@ class DistOperator:
                 return y + ell_apply(arrs["off_cols"], arrs["off_vals"], halo)
         with jax.named_scope("local"):
             xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
-            if "bcols" in arrs:
-                y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
-                return y[: self.rows_local]
-            return ell_apply(arrs["cols"], arrs["vals"], xfull)
+            return self._fused_product(arrs, xfull)
 
     # ------------------------------------------------------- host-side layout
     def scatter_x(self, x: np.ndarray, dtype=None) -> np.ndarray:

@@ -14,6 +14,10 @@
 * appends the finished span to a bounded ring shared by all threads, the
   oldest dropped first.
 
+``with span(...) as attrs:`` hands the block the span's attributes: what
+it adds there, such as a choice made inside, reaches the ring, while the
+profiler's annotation carries those given at entry.
+
 :func:`recent` returns a copy of the ring, :func:`clear` empties it.  A span
 times the host: never open one inside a function that ``jax.jit`` or
 ``shard_map`` traces, where it would time the trace (the lint's
@@ -72,7 +76,7 @@ def span(name: str, **attrs):
     try:
         with (contextlib.nullcontext() if jax is None
               else jax.profiler.TraceAnnotation(name, **attrs)):
-            yield
+            yield attrs
     finally:
         end = time.perf_counter_ns()
         _open.reset(token)

@@ -5,7 +5,9 @@ Operators with block structure (vector problems from
 blocks (bs ∈ {8, 16}) in a block-ELL layout and contract each block
 against a ``bs×k`` slab of the source — dense math instead of one scalar
 gather per nonzero.  :func:`repro.kernels.spmv.ops.select_dist_kernel`
-decides per level whether a level is lowered this way.
+decides per level whether a level is lowered this way; a level it keeps
+on ELL may still take the diagonal product for its on-process part
+(:mod:`repro.kernels.spmv.dia`) when that part is stencil-structured.
 
 Layout (produced by :func:`repro.amg.csr.csr_to_bcsr`):
 

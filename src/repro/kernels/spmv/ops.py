@@ -3,7 +3,11 @@
 :func:`select_local_kernel` / :func:`select_dist_kernel` are the layout
 heuristic the distributed solve phase uses to pick, per level, between
 the ELL gather product (:func:`repro.kernels.spmv.spmv.ell_apply`) and the
-block-ELL product (:func:`repro.kernels.spmv.bcsr.bcsr_apply`).
+block-ELL product (:func:`repro.kernels.spmv.bcsr.bcsr_apply`).  Where
+they keep ELL, :func:`select_dia` moves a stencil-structured on-process
+part to the diagonal product (:func:`repro.kernels.spmv.dia.dia_apply`):
+in the AMG hierarchies of grid problems, the fine level's ``A``; the
+Galerkin levels, ``P`` and ``R`` stay on ELL.
 """
 from __future__ import annotations
 
@@ -112,4 +116,27 @@ def select_dist_kernel(cols_stack: np.ndarray,
     return best
 
 
-__all__ = ["select_local_kernel", "select_dist_kernel", "MXU_ADVANTAGE"]
+def select_dia(cols_stack: np.ndarray) -> tuple[int, ...] | None:
+    """The offsets to lower a device-stacked ELL block ``cols_stack``
+    [D, n, K] (column ids local to each device) to DIA with, or None.
+
+    The offsets are the distinct ``col − row`` of the valid entries over
+    every device, ascending, so one offset set serves every device.  DIA
+    is taken when they number no more than the ELL width K: it then stores
+    no more values than ELL does and reads no column ids.
+    """
+    cols_stack = np.asarray(cols_stack)
+    keep = cols_stack >= 0
+    if not keep.any():
+        return None
+    n = cols_stack.shape[1]
+    diff = (cols_stack - np.arange(n, dtype=cols_stack.dtype)[:, None])[keep]
+    lo = int(diff.min())
+    offsets = np.flatnonzero(np.bincount(diff - lo)) + lo
+    if offsets.size > cols_stack.shape[2]:
+        return None
+    return tuple(int(o) for o in offsets)
+
+
+__all__ = ["select_local_kernel", "select_dist_kernel", "select_dia",
+           "MXU_ADVANTAGE"]

@@ -1,8 +1,12 @@
-"""The local ELL product every distributed apply runs on the device.
+"""The local ELL product the distributed applies run on the device.
 
 The sparse block is stored in ELL form (fixed K slots per padded row,
 ``cols == -1`` padding) — the layout the distributed solve path lowers
-every operator to.  :func:`ell_apply` contracts it against a source of
+every operator to first.  A stencil-structured on-process part (the fine
+level's ``A`` of a grid problem) is then moved to the diagonal product
+(:mod:`repro.kernels.spmv.dia`), which reads the source as shifted
+slices; the Galerkin levels, ``P``, ``R`` and every off-process part
+stay here or on block-ELL (:mod:`repro.kernels.spmv.bcsr`).  :func:`ell_apply` contracts it against a source of
 shape ``[m]`` (one right-hand side) or ``[m, k]`` (the native multi-RHS
 form: one pass over ``cols``/``vals`` serves all k columns).
 

@@ -5,10 +5,12 @@ Asserts that the device-resident ``backend="dist"`` V-cycle / stationary /
 PCG solves reproduce the host backend's residual histories to fp32
 tolerance for every halo strategy, that per-level model selection picks a
 non-standard strategy somewhere in the hierarchy, that the Pallas ELL
-kernel route agrees with the inline form, and that an fp64 ``AMGSolver``
+kernel route agrees with the inline form, that an fp64 ``AMGSolver``
 session's batched multi-RHS dist solve matches per-column host solves to
-1e-7 relative residual on the full 2x4 mesh.  Prints "OK <check>" per
-passing check; any exception fails the run.
+1e-7 relative residual on the full 2x4 mesh, and that a stencil's fine
+level lowers to DIA on a 2x2 and a 2x4 mesh and solves like the ELL
+oracle.  Prints "OK <check>" per passing check; any exception fails the
+run.
 """
 import os
 
@@ -354,7 +356,67 @@ def main():
     assert bound_s.pcg(b).converged
     print("OK streaming_refresh")
 
+    dia_layout()
     print("ALL_OK")
+
+
+def dia_layout():
+    """The 27-point stencil with three whole grid planes a device: L0's A
+    lowers to DIA on 27 diagonals on a 2x2 mesh (four of the eight
+    devices) and on 2x4, the Galerkin levels do not, PCG agrees with the
+    overlap=False ELL oracle and the host, forcing BCSR still lowers every
+    smoothing level to BCSR, and a value refresh equals a fresh
+    lowering."""
+    from repro.amg.csr import CSR
+    from repro.amg.dist_solve import DEV_AXES
+    from repro.amg.hierarchy import refresh_values
+
+    A = laplace_3d(24, 12, 12)
+    h = setup(A, solver="rs")
+    b = A.matvec(np.ones(A.nrows))
+    ref = pcg(h, b, tol=1e-6, maxiter=30)
+    mesh22 = jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                               DEV_AXES)
+    for pods, lanes, mesh in ((2, 2, mesh22), (N_PODS, LANES, None)):
+        dh = DistHierarchy.build(h, pods, lanes, mesh=mesh)
+        rows = dh.kernel_table()
+        assert rows[0]["kernel"] == "dia" and rows[0]["diagonals"] == 27, rows
+        assert not rows[0]["halo_empty"]
+        assert all(r["kernel"] != "dia" for r in rows[1:]), rows
+        got = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=dh)
+        oracle = DistHierarchy.build(h, pods, lanes, mesh=mesh,
+                                     overlap=False)
+        want = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=oracle)
+        assert got.converged and got.iterations == want.iterations
+        assert history_diff(ref.residuals, got.residuals) < TOL
+        assert history_diff(want.residuals, got.residuals) < TOL
+
+    import repro.amg.dist_solve as ds
+    pick = ds.select_dist_kernel
+    ds.select_dist_kernel = lambda cols: dict(pick(cols), kernel="bcsr",
+                                              block_size=8)
+    try:
+        dh_b = DistHierarchy.build(h, 2, 2, mesh=mesh22)
+    finally:
+        ds.select_dist_kernel = pick
+    assert all(dl.A.local_kernel == "bcsr" for dl in dh_b.levels[:-1])
+
+    dh = DistHierarchy.build(h, 2, 2, mesh=mesh22)
+    rng = np.random.default_rng(5)
+    d2 = A.data * (1.0 + 0.05 * rng.random(A.nnz))
+    At = CSR(A.shape, A.indptr.copy(), A.indices.copy(), d2).T
+    A2 = CSR(A.shape, A.indptr.copy(), A.indices.copy(), 0.5 * (d2 + At.data))
+    refresh_values(h, A2)
+    dh.refresh_values(h.levels)
+    fresh = DistHierarchy.build(h, 2, 2, mesh=mesh22)
+    assert dh.levels[0].A.dia_offsets == fresh.levels[0].A.dia_offsets
+    np.testing.assert_array_equal(dh.levels[0].A.dia_vals,
+                                  fresh.levels[0].A.dia_vals)
+    b2 = A2.matvec(np.ones(A.nrows))
+    x_r = pcg(h, b2, tol=0.0, maxiter=8, backend="dist", dist=dh).x
+    x_f = pcg(h, b2, tol=0.0, maxiter=8, backend="dist", dist=fresh).x
+    np.testing.assert_array_equal(np.asarray(x_r), np.asarray(x_f))
+    print("OK dia_layout")
 
 
 if __name__ == "__main__":

@@ -1,7 +1,9 @@
 """Distributed solve-phase tests.
 
 Host-side (no extra devices): rectangular halo-plan/ELL correctness, the
-per-level strategy-selection table, and backend dispatch on a 1x1 mesh.
+per-level strategy-selection table, backend dispatch on a 1x1 mesh, and
+the DIA lowering of a stencil's fine level (lossless, refreshed like a
+fresh lowering, PCG held to the ELL oracle and the host).
 Multi-device parity for all three strategies runs in a subprocess
 (``dist_solve_script.py``) so this pytest process keeps one CPU device.
 """
@@ -26,7 +28,7 @@ EXPECTED = [
     "OK auto_select", "OK bcsr_path", "OK chebyshev",
     "OK cycle_smoother_parity", "OK overlap_parity", "OK empty_halo",
     "OK comm_audit", "OK dist_setup_cycles", "OK multi_rhs",
-    "OK streaming_refresh",
+    "OK streaming_refresh", "OK dia_layout",
     "ALL_OK",
 ]
 
@@ -128,6 +130,137 @@ def test_backend_dispatch_single_device():
         solve(h, b, backend="dist")            # dist= is required
     with pytest.raises(ValueError):
         pcg(h, b, backend="dist", dist={"n_pods": 1})  # lanes missing
+
+
+def _drift(A, seed=13):
+    """Same pattern, new symmetric values."""
+    from repro.amg.csr import CSR
+
+    d = A.data * (1.0 + 0.05 * np.random.default_rng(seed).random(A.nnz))
+    At = CSR(A.shape, A.indptr.copy(), A.indices.copy(), d).T
+    return CSR(A.shape, A.indptr.copy(), A.indices.copy(), 0.5 * (d + At.data))
+
+
+def _dia_entries(op, d):
+    """(row, col, val) of device d's DIA lowering, stored zeros left out."""
+    vals = op.dia_vals[d].reshape(len(op.dia_offsets), -1)[:, :op.rows_local]
+    diag, r = np.nonzero(vals)
+    c = r + np.asarray(op.dia_offsets)[diag]
+    return sorted(zip(r.tolist(), c.tolist(), vals[diag, r].tolist()))
+
+
+@pytest.mark.parametrize("n_pods,lanes", [(1, 1), (2, 2)])
+def test_lower_dia_is_lossless_and_refreshes_like_a_fresh_lowering(
+        n_pods, lanes):
+    """Every on-process entry lands on its diagonal, per device (the
+    off-part stays ELL), and a value refresh gives the arrays a fresh
+    lowering of the new values gives."""
+    from repro.amg.dist_spmv import build_dist_operator
+    from repro.kernels.spmv.ops import select_dia
+
+    A = laplace_3d(12, 6, 6)    # 3 whole planes of 36 rows a device on 2x2
+    op = build_dist_operator(A, n_pods, lanes, "standard", dtype=np.float64)
+    offsets = select_dia(op.on_cols)
+    assert offsets is not None and len(offsets) == 27
+    op.lower_dia(offsets)
+    assert op.local_kernel == "dia"
+    arrs = op.device_arrays()
+    assert "dia" in arrs and "on_cols" not in arrs and "cols" in arrs
+    for d in range(op.n_devices):
+        keep = op.on_cols[d] >= 0
+        r = np.broadcast_to(np.arange(op.rows_local)[:, None],
+                            keep.shape)[keep]
+        want = sorted(zip(r.tolist(), op.on_cols[d][keep].tolist(),
+                          op.on_vals[d][keep].tolist()))
+        assert _dia_entries(op, d) == want
+    A2 = _drift(A)
+    op.refresh_values(lambda d: A2)
+    fresh = build_dist_operator(A2, n_pods, lanes, "standard",
+                                dtype=np.float64)
+    fresh.lower_dia(select_dia(fresh.on_cols))
+    assert op.dia_offsets == fresh.dia_offsets
+    np.testing.assert_array_equal(op.dia_vals, fresh.dia_vals)
+    np.testing.assert_array_equal(op.off_vals, fresh.off_vals)
+
+
+def test_fine_stencil_level_lowers_to_dia_on_one_device():
+    """laplace_3d on a 1x1 mesh: L0's A takes DIA on its 27 diagonals, the
+    Galerkin levels keep ELL or BCSR, one ``amg.lower.layout`` span a level
+    says so, and dist PCG agrees with the overlap=False ELL oracle and
+    with the host; after a value refresh it agrees with a fresh
+    lowering."""
+    from repro.amg import spans
+    from repro.amg.dist_solve import DistHierarchy
+    from repro.amg.hierarchy import refresh_values
+    from repro.kernels.spmv.ops import select_dia
+
+    A = laplace_3d(16)
+    h = setup(A, solver="rs")
+    before = {s.id for s in spans.recent()}
+    dh = DistHierarchy.build(h, 1, 1)
+    layout = [s.attrs for s in spans.recent()
+              if s.id not in before and s.name == "amg.lower.layout"]
+    rows = dh.kernel_table()
+    assert rows[0]["kernel"] == "dia" and rows[0]["diagonals"] == 27
+    assert dh.levels[0].local_kernel["kernel"] == "dia"
+    assert [r["kernel"] for r in rows[1:]] == [
+        dl.local_kernel["kernel"] for dl in dh.levels[1:]]
+    for dl in dh.levels[1:]:
+        assert dl.A.local_kernel in ("ell", "bcsr")
+        if dl.A.local_kernel == "ell":      # too many offsets for DIA
+            assert select_dia(dl.A.on_cols) is None
+    assert [a["level"] for a in layout] == list(range(len(dh.levels)))
+    assert [a["layout"] for a in layout] == [r["kernel"] for r in rows]
+    assert layout[0]["diagonals"] == 27
+    assert layout[0]["nnz"] == layout[0]["dia_nnz"] == A.nnz
+    assert all(a["dia_nnz"] == 0 for a in layout[1:])
+    assert [a["nnz"] for a in layout] == [lv.A.nnz for lv in h.levels]
+
+    b = A.matvec(np.ones(A.nrows))
+    res_h = pcg(h, b, tol=1e-6, maxiter=30)
+    res_d = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=dh)
+    oracle = DistHierarchy.build(h, 1, 1, overlap=False)
+    res_o = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=oracle)
+    assert res_d.converged and res_d.iterations == res_o.iterations
+    r0 = res_h.residuals[0]
+    for ref in (res_h.residuals, res_o.residuals):
+        n = min(len(ref), len(res_d.residuals))
+        for a, c in zip(ref[:n], res_d.residuals[:n]):
+            assert abs(a - c) / r0 < 2e-4
+
+    A2 = _drift(A)
+    refresh_values(h, A2)
+    dh.refresh_values(h.levels)
+    fresh = DistHierarchy.build(h, 1, 1)
+    for dl, fl in zip(dh.levels, fresh.levels):
+        assert dl.A.local_kernel == fl.A.local_kernel
+        if dl.A.dia_offsets is not None:
+            assert dl.A.dia_offsets == fl.A.dia_offsets
+            np.testing.assert_array_equal(dl.A.dia_vals, fl.A.dia_vals)
+    b2 = A2.matvec(np.ones(A.nrows))
+    x_r = pcg(h, b2, tol=0.0, maxiter=8, backend="dist", dist=dh).x
+    x_f = pcg(h, b2, tol=0.0, maxiter=8, backend="dist", dist=fresh).x
+    np.testing.assert_array_equal(np.asarray(x_r), np.asarray(x_f))
+
+
+def test_unstructured_operator_keeps_ell():
+    """A random sparse operator has more distinct offsets than ELL slots:
+    its A lowers op for op as ELL."""
+    from repro.amg.csr import CSR
+    from repro.amg.dist_solve import DistHierarchy
+
+    rng = np.random.default_rng(2)
+    n = 300
+    dense = np.where(rng.random((n, n)) < 0.02, -rng.random((n, n)), 0.0)
+    dense = dense + dense.T
+    np.fill_diagonal(dense, -dense.sum(axis=1) + 1.0)
+    r, c = np.nonzero(dense)
+    A = CSR.from_coo(r, c, dense[r, c], (n, n))
+    h = setup(A, solver="rs")
+    dh = DistHierarchy.build(h, 1, 1)
+    assert all(r["kernel"] != "dia" for r in dh.kernel_table())
+    assert all("on_cols" in a["A"] and "dia" not in a["A"]
+               for a in dh._arrs)
 
 
 def test_cycle_comm_stats_counts_and_smoothers():

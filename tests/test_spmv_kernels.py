@@ -3,8 +3,10 @@
 Covers the ELL product's multi-RHS form against both its vmapped
 single-RHS form and the host CSR oracle (fp32/fp64, ragged K, padded
 rows), BCSR round-trips and the block contraction's dense equivalence,
-the degenerate shapes (K == 0, n == 0, empty x, k == 0), and
-hypothesis-style random-sparsity sweeps under the deterministic stub."""
+the DIA product against the ELL oracles on random offset sets and on the
+27- and 7-point stencils, the degenerate shapes (K == 0, n == 0, empty
+x, k == 0), and hypothesis-style random-sparsity sweeps under the
+deterministic stub."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +16,9 @@ from hypothesis import given, settings, strategies as st
 from repro.amg.csr import CSR, csr_to_bcsr
 from repro.amg.problems import laplace_3d, laplace_3d_7pt
 from repro.kernels.spmv.bcsr import BLOCK_SIZES, bcsr_apply
-from repro.kernels.spmv.ops import select_dist_kernel, select_local_kernel
+from repro.kernels.spmv.dia import LANES, dia_apply, fold
+from repro.kernels.spmv.ops import (select_dia, select_dist_kernel,
+                                    select_local_kernel)
 from repro.kernels.spmv.ref import ell_spmm_ref, ell_spmv_ref
 from repro.kernels.spmv.spmv import ell_apply
 
@@ -232,3 +236,159 @@ def test_spmv_kernel_on_7pt_operator():
                    axis=1)
     np.testing.assert_allclose(np.asarray(out, np.float64), ref,
                                rtol=2e-4, atol=2e-4)
+
+
+# --------------------------------------------------------------------- DIA
+def _random_dia(rng, n, m, offsets, dtype, missing=0.2):
+    """A random operator on ``offsets`` as ELL (columns ascending, so slot
+    order is offset order) and as DIA [n_diag, n]; a ``missing`` share of
+    the in-range entries is left out, so rows lack diagonals."""
+    offsets = sorted(offsets)
+    i = np.arange(n)[:, None]
+    c = i + np.asarray(offsets)[None, :]
+    keep = (c >= 0) & (c < m) & (rng.random(c.shape) >= missing)
+    v = np.where(keep, rng.standard_normal(c.shape), 0.0).astype(dtype)
+    dia = np.ascontiguousarray(v.T)
+    K = max(int(keep.sum(axis=1).max(initial=0)), 1)
+    cols = np.full((n, K), -1, dtype=np.int32)
+    vals = np.zeros((n, K), dtype=dtype)
+    r, j = np.nonzero(keep)
+    slot = np.arange(r.size) - np.repeat(
+        np.concatenate([[0], np.cumsum(keep.sum(axis=1))[:-1]]),
+        keep.sum(axis=1))
+    cols[r, slot] = c[r, j]
+    vals[r, slot] = v[r, j]
+    return tuple(offsets), dia, jnp.asarray(cols), jnp.asarray(vals)
+
+
+def _dtype(dtype):
+    if dtype == np.float64 and not jax.config.jax_enable_x64:
+        return np.float32     # x64 disabled in-process: still run the shape
+    return dtype
+
+
+DIA_CASES = {
+    "single-main": (50, 50, [0]),
+    "single-off": (50, 50, [3]),
+    "stencil-1d": (64, 64, [-1, 0, 1]),
+    "reach-n-1": (40, 40, [-39, -5, 0, 7, 39]),
+    "rect-wide": (30, 70, [-2, 0, 11, 45]),
+    "rect-narrow": (90, 33, [-50, -1, 0, 2]),
+    "fold-exact": (2 * LANES, 2 * LANES, [-LANES, -1, 0, 1, LANES]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DIA_CASES))
+@pytest.mark.parametrize("k", [0, 3])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_dia_matches_ell_and_csr(case, k, dtype):
+    """y = Σ_d vals[d]·x[i + offsets[d]] against the ELL oracles and the
+    host CSR product, one right-hand side (k=0) or three."""
+    n, m, offs = DIA_CASES[case]
+    dtype = _dtype(dtype)
+    rng = np.random.default_rng(n + m + k)
+    offsets, dia, cols, vals = _random_dia(rng, n, m, offs, dtype)
+    x = rng.standard_normal((m,) if k == 0 else (m, k)).astype(dtype)
+    out = dia_apply(offsets, jnp.asarray(fold(dia)), jnp.asarray(x))
+    assert out.shape == ((-(-n // LANES) * LANES,) + x.shape[1:])
+    # the folded rows past n hold stored zeros: they read exactly zero
+    np.testing.assert_array_equal(np.asarray(out)[n:], 0.0)
+    out = np.asarray(out)[:n]
+    tol = TOL[np.dtype(dtype)]
+    ref = (ell_spmv_ref if k == 0 else ell_spmm_ref)(cols, vals,
+                                                     jnp.asarray(x))
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=tol, atol=tol)
+    np.testing.assert_allclose(out, np.asarray(ell_apply(cols, vals,
+                                                         jnp.asarray(x))),
+                               rtol=tol, atol=tol)
+    A = _ell_to_csr(cols, vals, m)
+    x64 = x.astype(np.float64).reshape(m, -1)
+    csr = np.stack([A.matvec(x64[:, j]) for j in range(x64.shape[1])], 1)
+    np.testing.assert_allclose(out.reshape(n, -1), csr, rtol=tol, atol=tol)
+
+
+def test_dia_degenerate_shapes():
+    """No diagonals, or an empty source, give exact zeros."""
+    f32 = jnp.float32
+    y = dia_apply((), jnp.zeros((0, 1, LANES), f32), jnp.ones((7,), f32))
+    np.testing.assert_array_equal(np.asarray(y), np.zeros(LANES))
+    y = dia_apply((-1, 0), jnp.zeros((2, 1, LANES), f32),
+                  jnp.zeros((0, 3), f32))
+    np.testing.assert_array_equal(np.asarray(y), np.zeros((LANES, 3)))
+
+
+def test_fold_pads_rows_with_zeros():
+    v = np.arange(6.0).reshape(2, 3)
+    f = fold(v)
+    assert f.shape == (2, 1, LANES)
+    np.testing.assert_array_equal(f.reshape(2, -1)[:, :3], v)
+    np.testing.assert_array_equal(f.reshape(2, -1)[:, 3:], 0.0)
+
+
+def _csr_ell(A, dtype=np.float32):
+    K = int(np.diff(A.indptr).max())
+    cols = np.full((A.nrows, K), -1, dtype=np.int32)
+    vals = np.zeros((A.nrows, K), dtype=dtype)
+    lens = np.diff(A.indptr)
+    slot = np.arange(A.nnz) - np.repeat(A.indptr[:-1], lens)
+    cols[A.rows_expanded(), slot] = A.indices
+    vals[A.rows_expanded(), slot] = A.data
+    return cols, vals
+
+
+@pytest.mark.parametrize("problem,n_diag", [(laplace_3d, 27),
+                                            (laplace_3d_7pt, 7)])
+def test_dia_equals_ell_on_stencils(problem, n_diag):
+    """The stencils in natural order: every row's columns sit on n_diag
+    fixed offsets, so select_dia takes them, and the DIA product equals
+    the ELL one, for one and for four right-hand sides."""
+    A = problem(7)
+    cols, vals = _csr_ell(A)
+    offsets = select_dia(cols[None])
+    assert offsets is not None and len(offsets) == n_diag
+    assert list(offsets) == sorted(offsets) and 0 in offsets
+    dia = np.zeros((n_diag, A.nrows), dtype=np.float32)
+    keep = cols >= 0
+    r = np.broadcast_to(np.arange(A.nrows)[:, None], cols.shape)[keep]
+    dia[np.searchsorted(offsets, cols[keep] - r), r] = vals[keep]
+    X = np.random.default_rng(n_diag).standard_normal(
+        (A.nrows, 4)).astype(np.float32)
+    tol = TOL[np.dtype(np.float32)]
+    for x in (X[:, 0], X):
+        out = dia_apply(offsets, jnp.asarray(fold(dia)), jnp.asarray(x))
+        ell = ell_apply(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
+        np.testing.assert_allclose(np.asarray(out)[: A.nrows],
+                                   np.asarray(ell), rtol=tol, atol=tol)
+
+
+def test_select_dia_rule():
+    """DIA is taken only where its diagonals number no more than the ELL
+    width; an unstructured block keeps ELL."""
+    rng = np.random.default_rng(5)
+    cols, _ = _random_ell(rng, 200, 200, 6, np.float32)
+    assert select_dia(np.asarray(cols)[None]) is None
+    assert select_dia(np.full((1, 4, 2), -1, np.int32)) is None
+    # a tridiagonal block over two devices: offsets are the union
+    tri = np.array([[-1, 0, 1], [0, 1, 2], [1, 2, -1]], np.int32)
+    two = np.stack([tri, np.array([[0, 1, -1], [0, 1, 2], [1, 2, -1]],
+                                  np.int32)])
+    assert select_dia(two) == (-1, 0, 1)
+    # four offsets on a width-3 block: DIA would store more than ELL
+    assert select_dia(np.array([[[0, 1, 2], [0, -1, -1]]], np.int32)) is None
+
+
+@settings(max_examples=12, deadline=None)
+@given(st.integers(1, 150), st.integers(1, 150), st.integers(1, 6),
+       st.integers(1, 4), st.integers(0, 10 ** 6))
+def test_dia_random_offsets(n, m, n_diag, k, seed):
+    rng = np.random.default_rng(seed)
+    offs = rng.choice(np.arange(-(n - 1), m), size=min(n_diag, n + m - 1),
+                      replace=False)
+    offsets, dia, cols, vals = _random_dia(rng, n, m, offs.tolist(),
+                                           np.float32,
+                                           missing=float(rng.random()) * 0.5)
+    X = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    out = np.asarray(dia_apply(offsets, jnp.asarray(fold(dia)), X))[:n]
+    tol = TOL[np.dtype(np.float32)]
+    np.testing.assert_allclose(out, np.asarray(ell_spmm_ref(cols, vals, X)),
+                               rtol=tol, atol=tol)

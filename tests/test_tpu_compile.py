@@ -16,6 +16,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
                           SingleDeviceSharding)
 
 from repro.kernels.spmv.bcsr import bcsr_apply  # noqa: E402
+from repro.kernels.spmv.dia import LANES, dia_apply  # noqa: E402
 from repro.kernels.spmv.spmv import ell_apply  # noqa: E402
 
 ROWS = 104 ** 3        # HPCG's reference local grid: 1,124,864 rows
@@ -25,6 +26,14 @@ K = 27                 # the 27-point stencil's ELL width
 # form it replaced planned 1.73 GB (k=1) and 5.83 GB (k=8).
 ELL_TEMP_LIMIT = 8 << 20
 BCSR_TEMP_LIMIT = {1: 73_000_000, 8: 2_215_000_000}
+# dia_apply plans no scratch at k=1 (one fusion streams the 27 diagonals
+# and the shifted source) and 40.9 MB at k=8, one padded copy of the
+# [rows, 8] source
+DIA_TEMP_LIMIT = {1: 1 << 20, 8: 48 << 20}
+# the 27 stencil offsets of the 104³ grid in natural order
+DIA_OFFSETS = tuple(sorted(dz * 104 * 104 + dy * 104 + dx
+                           for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+                           for dx in (-1, 0, 1)))
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +83,24 @@ def test_bcsr_apply_compiles_at_hpcg_size(one_chip, k):
     # what XLA plans today: the gathered [mb, bs, k] slab is tile-padded
     # on its two minor dims (72 MB at k=1, 2.21 GB at k=8); it must not grow
     assert mem.temp_size_in_bytes <= BCSR_TEMP_LIMIT[k], mem
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_dia_apply_compiles_at_hpcg_size(one_chip, k):
+    """The 27 diagonals folded onto 128 lanes: at k=1 the product moves
+    what it must, each diagonal, the source and the result once (an
+    unfolded [27, rows] array would be relaid out every call, 373.6 MB)."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    x = s((ROWS,) if k == 1 else (ROWS, k), jnp.float32)
+    fn = lambda vals, x: dia_apply(DIA_OFFSETS, vals, x)
+    compiled = jax.jit(fn).lower(
+        s((K, ROWS // LANES, LANES), jnp.float32), x).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= DIA_TEMP_LIMIT[k], mem
+    if k == 1:
+        moved = compiled.cost_analysis()["bytes accessed"]
+        assert moved <= 1.01 * 4 * (K + 2) * ROWS, moved
 
 
 @pytest.mark.parametrize("name", ["pcg_step", "pcg_step_m"])
