@@ -151,7 +151,15 @@ class AMGConfig:
     """Frozen, hashable description of a full solver session: setup knobs,
     smoother options, iteration defaults, and backend/mesh/strategy
     knobs.  Hashability is what makes it a cache key — two configs that
-    compare equal always produce interchangeable solvers."""
+    compare equal always produce interchangeable solvers.
+
+    ``dtype`` is the precision of the answers.  On the dist backend the
+    device arrays hold it where JAX can: ``"float64"`` on a device without
+    float64 (a TPU, or any device with ``jax_enable_x64`` off) lowers the
+    hierarchy in float32, and ``pcg``/``solve`` then return float64 answers
+    held to ``tol`` in the float64 true residual by iterative refinement
+    around the float32 device solve (:func:`~repro.amg.api.sessions.refine`).
+    """
 
     # -- setup phase (Algorithm 1)
     solver: str = "rs"                   # "rs" | "sa"

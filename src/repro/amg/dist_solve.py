@@ -168,7 +168,9 @@ class DistHierarchy:
     Built once per hierarchy (like the MPI communicator build of a parallel
     AMG code); reusable across any number of :func:`dist_solve` /
     :func:`dist_pcg` calls.  Compiled V-cycle programs are cached per solver
-    option set.
+    option set.  ``dtype`` is what the device arrays really hold, whatever
+    precision was asked for: float64 narrows to float32 unless
+    ``jax_enable_x64`` is on (a TPU has no native float64).
     """
 
     def __init__(self, h: Hierarchy | None, n_pods: int, lanes: int,
@@ -181,7 +183,7 @@ class DistHierarchy:
         self.n_pods, self.lanes = n_pods, lanes
         self.levels = levels
         self.mesh = mesh
-        self.dtype = dtype
+        self.dtype = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
         self.reduce_strategy = reduce_strategy
         # multi-RHS routing: True traces the ``*_m`` programs directly on
         # [local, k] operands (native SpMM — one pass over each operator's
