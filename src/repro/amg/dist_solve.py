@@ -370,6 +370,14 @@ class DistHierarchy:
                     modeled.update(interp=tP, restrict=tR)
                     comm_stats["interp"] = schedule_comm_stats(gP, sP)
                     comm_stats["restrict"] = schedule_comm_stats(gR, sR)
+                with span("amg.lower.remote", level=l) as remote:
+                    # the rows the off-process products run over, of the
+                    # level's operators that exchange a halo
+                    haloed = [op for op in (Aop, Pop, Rop)
+                              if op is not None and not op.halo_empty]
+                    remote.update(
+                        rows=sum(op.rows_local for op in haloed),
+                        remote_rows=sum(op.remote_rows for op in haloed))
             with span("amg.lower.factors", level=l):
                 dl = DistLevel(A=Aop, dinv=_dinv_blocks(lv.A, part), P=Pop,
                                R=Rop, strategies=chosen, modeled=modeled,

@@ -21,7 +21,8 @@ Execute (device, every smoother sweep / residual / restrict / interpolate):
   (:func:`repro.kernels.spmv.spmv.ell_apply`, or
   :func:`repro.kernels.spmv.bcsr.bcsr_apply` on a BCSR-lowered level, or
   :func:`repro.kernels.spmv.dia.dia_apply` for a DIA-lowered on-process
-  part).
+  part) → the off-process product over the rows that read the halo
+  (:func:`remote_apply`).
 """
 from __future__ import annotations
 
@@ -93,6 +94,37 @@ def _split_ell_stacked(cols: np.ndarray, vals: np.ndarray, x_local: int):
     return on, off
 
 
+def _remote_rows(off_cols: np.ndarray) -> np.ndarray | None:
+    """The sorted ``[D, R_off]`` list of each device's local rows that hold
+    a halo entry, ``R_off`` the largest count over the devices; ``None``
+    where some device has a halo entry in every row, so a row list saves
+    nothing, or where no row has one, so the apply never reaches the
+    off-part.
+
+    A device with fewer such rows is padded with its first rows that hold
+    none: their off-part row is empty, so they add zero, and the list stays
+    sorted and free of repeats.
+    """
+    has = (off_cols >= 0).any(axis=2)
+    R_off = int(has.sum(axis=1).max(initial=0))
+    if R_off in (0, has.shape[1]):
+        return None
+    first = np.argsort(~has, axis=1, kind="stable")[:, :R_off]
+    return np.sort(first, axis=1).astype(np.int32)
+
+
+def remote_apply(y: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
+                 halo: jnp.ndarray, rows: jnp.ndarray | None = None):
+    """``y + A_off·halo``.  With a row list ``rows`` (see
+    :func:`_remote_rows`), ``cols``/``vals`` hold only those rows and the
+    product is added into them with one scatter; without, they hold every
+    row.  Either way each row sums the same terms in the same order."""
+    if rows is None:
+        return y + ell_apply(cols, vals, halo)
+    return y.at[rows].add(ell_apply(cols, vals, halo),
+                          indices_are_sorted=True, unique_indices=True)
+
+
 @dataclasses.dataclass
 class DistOperator:
     """Host-side container for one distributed (possibly rectangular) operator.
@@ -119,6 +151,12 @@ class DistOperator:
     on_vals: np.ndarray | None = None
     off_cols: np.ndarray | None = None   # [D, rows_local, K_off] into halo
     off_vals: np.ndarray | None = None
+    # the off-part compacted to the rows that hold a halo entry (see
+    # _remote_rows); None where that saves nothing, and the full-row
+    # off-part runs instead
+    off_rows: np.ndarray | None = None   # [D, R_off] int32, sorted
+    off_ccols: np.ndarray | None = None  # [D, R_off, K_off]
+    off_cvals: np.ndarray | None = None
     # optional BCSR lowering (see lower_bcsr): dense bs×bs blocks contracted
     # per block slot instead of one gather per nonzero
     bcsr_bcols: np.ndarray | None = None   # [D, mb, Kb] int32, -1 pad
@@ -156,6 +194,22 @@ class DistOperator:
         halo is (the comm auditor's per-operator contract)."""
         return halo_signature(self.plan)
 
+    @property
+    def remote_rows(self) -> int:
+        """Rows the off-process product runs over on each device: every
+        row where the off-part is not compacted."""
+        return self.rows_local if self.off_rows is None \
+            else self.off_rows.shape[1]
+
+    def compact_remote(self, rows: np.ndarray | None) -> None:
+        """Gather the off-part's rows ``rows`` (``None``: keep every row)."""
+        self.off_rows = rows
+        if rows is None:
+            self.off_ccols = self.off_cvals = None
+            return
+        self.off_ccols = np.take_along_axis(self.off_cols, rows[..., None], 1)
+        self.off_cvals = np.take_along_axis(self.off_vals, rows[..., None], 1)
+
     def onoff_nnz(self) -> dict[str, int]:
         """Total and per-device-max nnz of the on/off split (for the
         overlap-aware cost model and reporting)."""
@@ -171,6 +225,9 @@ class DistOperator:
                 "send": self.send_idx, "recv": self.recv_sel,
                 "psel": self.pool_sel,
                 "off_cols": self.off_cols, "off_vals": self.off_vals}
+        if self.off_rows is not None:
+            arrs.update(off_rows=self.off_rows, off_cols=self.off_ccols,
+                        off_vals=self.off_cvals)
         if self.dia_vals is not None:
             arrs["dia"] = self.dia_vals
         else:
@@ -265,7 +322,8 @@ class DistOperator:
         a frozen pattern the column maps, halo plan and on/off split
         layouts are all reproduced exactly; only the value planes change.
         BCSR lowerings are re-tiled at the same ``block_size``, DIA
-        lowerings re-filled on the same offsets.
+        lowerings re-filled on the same offsets, the compacted off-part
+        re-gathered on the same row list.
         """
         vals = np.zeros(self.ell_cols.shape, dtype=np.float64)
         for d in range(self.n_devices):
@@ -283,6 +341,7 @@ class DistOperator:
         # the split is deterministic given cols: layouts come back identical
         self.on_cols, self.on_vals = on_cols, on_vals
         self.off_cols, self.off_vals = off_cols, off_vals
+        self.compact_remote(self.off_rows)
         if self.block_size:
             self.lower_bcsr(self.block_size)
         if self.dia_offsets is not None:
@@ -325,7 +384,8 @@ class DistOperator:
 
         The ops carry the scope ``halo`` (the exchange with its packing),
         ``local`` (``A_on·x``; the whole fused product in the serial form)
-        or ``remote`` (``A_off·halo``).
+        or ``remote`` (``A_off·halo``, over the rows that read the halo
+        where the off-part was compacted).
         """
         if self.halo_empty:
             with jax.named_scope("local"):
@@ -344,7 +404,8 @@ class DistOperator:
             with jax.named_scope("local"):
                 y = self._on_product(arrs, x_loc)
             with jax.named_scope("remote"):
-                return y + ell_apply(arrs["off_cols"], arrs["off_vals"], halo)
+                return remote_apply(y, arrs["off_cols"], arrs["off_vals"],
+                                    halo, arrs.get("off_rows"))
         with jax.named_scope("local"):
             xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
             return self._fused_product(arrs, xfull)
@@ -421,12 +482,14 @@ def _assemble_operator(block_of, K: int, n_pods: int, lanes: int,
     vals = vals.astype(dtype)
     (on_cols, on_vals), (off_cols, off_vals) = _split_ell_stacked(
         cols, vals, x_local)
-    return DistOperator(strategy=strategy, plan=plan, row_part=row_part,
-                        col_part=col_part, rows_local=rows_local,
-                        ell_cols=cols, ell_vals=vals,
-                        send_idx=plan.send_idx, recv_sel=plan.recv_sel,
-                        pool_sel=psel, on_cols=on_cols, on_vals=on_vals,
-                        off_cols=off_cols, off_vals=off_vals)
+    op = DistOperator(strategy=strategy, plan=plan, row_part=row_part,
+                      col_part=col_part, rows_local=rows_local,
+                      ell_cols=cols, ell_vals=vals,
+                      send_idx=plan.send_idx, recv_sel=plan.recv_sel,
+                      pool_sel=psel, on_cols=on_cols, on_vals=on_vals,
+                      off_cols=off_cols, off_vals=off_vals)
+    op.compact_remote(_remote_rows(off_cols))
+    return op
 
 
 def build_dist_operator(M: CSR, n_pods: int, lanes: int, strategy: str,

@@ -7,6 +7,7 @@ fresh lowering, PCG held to the ELL oracle and the host).
 Multi-device parity for all three strategies runs in a subprocess
 (``dist_solve_script.py``) so this pytest process keeps one CPU device.
 """
+import json
 import os
 import pathlib
 import subprocess
@@ -181,6 +182,118 @@ def test_lower_dia_is_lossless_and_refreshes_like_a_fresh_lowering(
     assert op.dia_offsets == fresh.dia_offsets
     np.testing.assert_array_equal(op.dia_vals, fresh.dia_vals)
     np.testing.assert_array_equal(op.off_vals, fresh.off_vals)
+
+
+def test_refresh_keeps_the_remote_row_list():
+    """A value refresh re-gathers the compacted off-part on the frozen row
+    list, and gives the row list and values of a fresh lowering of the new
+    values, for A, P and R."""
+    from repro.amg.csr import CSR
+    from repro.amg.dist_spmv import build_dist_operator
+
+    h = setup(laplace_3d(12, 6, 6), solver="rs", max_coarse=30)
+    topo = Topology(n_nodes=2, ppn=2)
+    fp = Partition.balanced(h.levels[0].A.nrows, topo)
+    cp = Partition.balanced(h.levels[0].P.ncols, topo)
+    rng = np.random.default_rng(4)
+    for name, rp_, cp_ in (("A", fp, fp), ("P", fp, cp), ("R", cp, fp)):
+        M = getattr(h.levels[0], name)
+        M2 = CSR(M.shape, M.indptr.copy(), M.indices.copy(),
+                 M.data * (1 + rng.random(M.nnz)))
+        op = build_dist_operator(M, 2, 2, "nap2", row_part=rp_,
+                                 col_part=cp_, dtype=np.float64)
+        rows = op.off_rows
+        assert rows is not None and op.remote_rows < op.rows_local, name
+        op.refresh_values(lambda d: M2)
+        fresh = build_dist_operator(M2, 2, 2, "nap2", row_part=rp_,
+                                    col_part=cp_, dtype=np.float64)
+        assert op.off_rows is rows
+        np.testing.assert_array_equal(op.off_rows, fresh.off_rows)
+        np.testing.assert_array_equal(op.off_ccols, fresh.off_ccols)
+        np.testing.assert_array_equal(op.off_cvals, fresh.off_cvals)
+
+
+REMOTE_ON_FOUR = """
+import json
+import jax
+jax.config.update("jax_enable_x64", True)
+import jax.numpy as jnp
+import numpy as np
+from repro.amg import setup
+from repro.amg.dist_solve import DistHierarchy
+from repro.amg.problems import laplace_3d
+
+h = setup(laplace_3d(16), solver="rs")
+rng = np.random.default_rng(0)
+out = []
+for strategy in ("auto", "standard", "nap2", "nap3"):
+    dh = DistHierarchy.build(h, 2, 2, strategy=strategy, dtype=jnp.float64)
+    spec = dh._dev_spec
+    for l, (lv, dl) in enumerate(zip(h.levels, dh.levels)):
+        for name in ("A", "P", "R"):
+            op = getattr(dl, name)
+            if op is None:
+                continue
+            M = getattr(lv, name)
+            for k in (1, 8):
+                x = rng.standard_normal((M.ncols,) if k == 1 else (M.ncols, k))
+                xd = jax.device_put(op.scatter_x(x), dh._sharding)
+                y = {}
+                for overlap in (True, False):
+                    def body(x, a, op=op, overlap=overlap):
+                        a = jax.tree_util.tree_map(lambda v: v[0], a)
+                        return op.apply(a, x[0], overlap=overlap)[None]
+                    fn = jax.jit(jax.shard_map(
+                        body, mesh=dh.mesh, in_specs=(spec, spec),
+                        out_specs=spec, check_vma=False))
+                    y[overlap] = op.gather_y(np.asarray(fn(xd, dh._arrs[l][name])))
+                want = M.to_dense() @ x
+                scale = np.abs(want).max()
+                out.append(dict(
+                    strategy=strategy, level=l, op=name, k=k,
+                    halo=not op.halo_empty, compacted=op.off_rows is not None,
+                    rows=op.rows_local, remote_rows=op.remote_rows,
+                    oracle=float(np.abs(y[True] - y[False]).max() / scale),
+                    host=float(np.abs(y[True] - want).max() / scale)))
+print("REMOTE", json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def remote_on_four():
+    """Every level's A, P and R applied on a 2×2 mesh of host devices, in
+    float64, overlapped and serial, for each strategy and k = 1, 8."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    root = str(pathlib.Path(__file__).parents[1] / "src")
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", REMOTE_ON_FOUR],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-3000:]
+    line = next(ln for ln in out.stdout.splitlines()
+                if ln.startswith("REMOTE "))
+    return json.loads(line[len("REMOTE "):])
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_overlapped_apply_matches_serial_oracle_on_2x2(remote_on_four, k):
+    """The overlapped apply — its off-process product over the compacted
+    row list where there is one — equals the fused serial oracle
+    (``overlap=False``) and the host product, for A, P and R on every
+    level; the fine level's A runs over a row list, and an operator with a
+    halo entry in every row runs over every row."""
+    rows = [r for r in remote_on_four if r["k"] == k]
+    assert len({(r["level"], r["op"]) for r in rows}) >= 7
+    for r in rows:
+        assert r["oracle"] < 1e-13 and r["host"] < 1e-13, r
+        assert r["remote_rows"] <= r["rows"]
+        assert r["compacted"] == (r["halo"] and r["remote_rows"] < r["rows"])
+    assert all(r["compacted"] for r in rows
+               if r["level"] == 0 and r["op"] == "A")
+    assert any(r["compacted"] for r in rows if r["op"] == "P")
+    assert any(r["compacted"] for r in rows if r["op"] == "R")
+    assert any(r["halo"] and not r["compacted"] for r in rows)
 
 
 def test_fine_stencil_level_lowers_to_dia_on_one_device():

@@ -22,36 +22,57 @@ from repro.amg.dist import rect_vector_graph
 N_PODS, LANES = 2, 4
 
 
-def _random_csr(n=96, seed=0):
+def _random_csr(n=96, seed=0, fill=0.08):
+    """A band of half-width 3 plus random fill: with fill every row of a
+    device reaches another device's columns, without it only the three
+    rows next to each device boundary do."""
     rng = np.random.default_rng(seed)
     band = (np.abs(np.subtract.outer(np.arange(n), np.arange(n))) <= 3)
     dense = band * rng.normal(size=(n, n))
-    dense += np.where(rng.random((n, n)) < 0.08, rng.normal(size=(n, n)), 0.0)
+    dense += np.where(rng.random((n, n)) < fill, rng.normal(size=(n, n)), 0.0)
     r, c = np.nonzero(dense)
     return CSR.from_coo(r, c, dense[r, c], (n, n)), dense
 
 
-def _ell_entries(cols, vals):
-    """Multiset of (row, col, val) triples of one device's ELL block."""
+def _ell_entries(cols, vals, rows=None):
+    """Multiset of (row, col, val) triples of one device's ELL block; row
+    ``i`` of the block is local row ``rows[i]`` where a row list is given."""
     keep = cols >= 0
-    r = np.broadcast_to(np.arange(cols.shape[0])[:, None], cols.shape)[keep]
+    ids = np.arange(cols.shape[0]) if rows is None else rows
+    r = np.broadcast_to(ids[:, None], cols.shape)[keep]
     return sorted(zip(r.tolist(), cols[keep].tolist(), vals[keep].tolist()))
 
 
+@pytest.mark.parametrize("fill", [0.08, 0.0])
 @pytest.mark.parametrize("strategy", ["standard", "nap2", "nap3"])
-def test_split_partitions_fused_entries_exactly(strategy):
+def test_split_partitions_fused_entries_exactly(strategy, fill):
     """A_on (local ids) + A_off (halo ids, rebased) must hold *exactly* the
     fused block's entries: on = fused entries with col < x_local, off = the
-    rest shifted by x_local — per device, as multisets."""
-    A, _ = _random_csr()
+    rest shifted by x_local — per device, as multisets.  Where some row
+    holds no halo entry the off-part is also compacted: its row list holds
+    every row with a halo entry, padded with rows that hold none, sorted
+    and without repeats, and the compacted rows mapped back through it hold
+    exactly the off entries."""
+    A, _ = _random_csr(fill=fill)
     op = build_dist_operator(A, N_PODS, LANES, strategy, dtype=np.float64)
     x_local = op.plan.local_n
+    has = (op.off_cols >= 0).any(axis=2)
+    assert (op.off_rows is None) == bool(fill)
+    R_off = op.remote_rows
+    assert R_off == (op.rows_local if fill else has.sum(axis=1).max())
     for d in range(op.n_devices):
         fused = _ell_entries(op.ell_cols[d], op.ell_vals[d])
         want_on = [e for e in fused if e[1] < x_local]
         want_off = [(r, c - x_local, v) for r, c, v in fused if c >= x_local]
         assert _ell_entries(op.on_cols[d], op.on_vals[d]) == want_on
         assert _ell_entries(op.off_cols[d], op.off_vals[d]) == want_off
+        if op.off_rows is None:
+            continue
+        rows = op.off_rows[d]
+        assert rows.shape == (R_off,) and np.all(np.diff(rows) > 0)
+        assert set(np.flatnonzero(has[d])) <= set(rows.tolist())
+        assert _ell_entries(op.off_ccols[d], op.off_cvals[d], rows) \
+            == want_off
 
 
 def test_split_numeric_parity_per_device():
@@ -82,6 +103,48 @@ def test_split_numeric_parity_per_device():
         # and both match the dense row block
         y = dense[lo:hi] @ x
         np.testing.assert_allclose(split[: hi - lo], y, rtol=0, atol=1e-12)
+
+
+@pytest.mark.parametrize("strategy", ["standard", "nap2", "nap3"])
+def test_remote_rows_add_what_every_row_adds(strategy):
+    """The compacted off-process product equals the full-row one bit for
+    bit, in float32, on every device: the end devices' padded rows and
+    every row without a halo entry add nothing.  Integer values keep every
+    sum exact, so the two agree whether or not the compiler fuses a
+    multiply into an add."""
+    jnp = pytest.importorskip("jax.numpy")
+    from repro.amg.dist_spmv import remote_apply
+    A, _ = _random_csr(seed=17, fill=0.0)
+    A = CSR(A.shape, A.indptr, A.indices, np.round(4 * A.data))
+    op = build_dist_operator(A, N_PODS, LANES, strategy)
+    counts = (op.off_cols >= 0).any(axis=2).sum(axis=1)
+    assert counts.min() < op.remote_rows < op.rows_local   # end devices pad
+    rng = np.random.default_rng(2)
+    for k in ((), (8,)):
+        for d in range(op.n_devices):
+            y = jnp.asarray(rng.integers(-9, 10, (op.rows_local,) + k),
+                            jnp.float32)
+            halo = jnp.asarray(rng.integers(-9, 10, (op.plan.halo_len,) + k),
+                               jnp.float32)
+            full = remote_apply(y, op.off_cols[d], op.off_vals[d], halo)
+            rows = remote_apply(y, op.off_ccols[d], op.off_cvals[d], halo,
+                                op.off_rows[d])
+            np.testing.assert_array_equal(np.asarray(rows), np.asarray(full))
+            pad = ~(op.off_ccols[d] >= 0).any(axis=1)
+            assert pad.sum() == op.remote_rows - counts[d]
+            assert not op.off_cvals[d][pad].any()
+
+
+def test_operator_with_halo_in_every_row_keeps_full_rows():
+    """A row list saves nothing where some device has a halo entry in
+    every row: the off-part stays full-row and ships as such."""
+    A, _ = _random_csr(seed=5)
+    op = build_dist_operator(A, N_PODS, LANES, "standard", dtype=np.float64)
+    assert (op.off_cols >= 0).any(axis=2).all(axis=1).any()
+    assert op.off_rows is None and op.off_ccols is None
+    assert op.remote_rows == op.rows_local
+    arrs = op.device_arrays()
+    assert "off_rows" not in arrs and arrs["off_cols"] is op.off_cols
 
 
 def test_onoff_nnz_partitions_local_nnz():

@@ -103,6 +103,40 @@ def test_dia_apply_compiles_at_hpcg_size(one_chip, k):
         assert moved <= 1.01 * 4 * (K + 2) * ROWS, moved
 
 
+# the off-process product of L0 A on a middle chip of the 2×2 HPCG cell:
+# 26 planes of 104² rows a chip, two neighbour planes in the halo, the
+# off-part's widest row 9, and one row a halo entry in the two edge planes
+REMOTE_ROWS = 26 * 104 * 104
+REMOTE_HALO = 2 * 104 * 104
+REMOTE_K = 9
+# XLA plans 1.7 MB of scratch for the full-row product and none for the
+# compacted one; it counts 1.19 GB accessed for the first, 0.28 GB for the
+# second (each slot's gather is counted at more than its bytes)
+REMOTE_TEMP_LIMIT = 4 << 20
+
+
+def test_remote_apply_over_a_row_list_compiles_at_hpcg_2x2_size(one_chip):
+    """The row-compacted off-process product moves at most a quarter of
+    the bytes of the full-row one it replaces, in plain XLA."""
+    from repro.amg.dist_spmv import remote_apply
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    y = s((REMOTE_ROWS,), jnp.float32)
+    halo = s((REMOTE_HALO,), jnp.float32)
+    moved = {}
+    for rows in (REMOTE_ROWS, REMOTE_HALO):
+        args = [y, s((rows, REMOTE_K), jnp.int32),
+                s((rows, REMOTE_K), jnp.float32), halo]
+        if rows < REMOTE_ROWS:
+            args.append(s((rows,), jnp.int32))
+        compiled = jax.jit(remote_apply).lower(*args).compile()
+        assert "tpu_custom_call" not in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= REMOTE_TEMP_LIMIT
+        moved[rows] = compiled.cost_analysis()["bytes accessed"]
+    assert moved[REMOTE_HALO] <= moved[REMOTE_ROWS] / 4, moved
+
+
 @pytest.mark.parametrize("name", ["pcg_step", "pcg_step_m"])
 def test_fused_pcg_program_compiles(topo, name):
     """The whole fused PCG iteration of a 1-chip session, with its
