@@ -84,16 +84,14 @@ def rows(smoke: bool | None = None, cycles: int | None = None):
     return out
 
 
-def overlap_rows(smoke: bool | None = None, cycles: int | None = None):
-    """Per-level on/off operator splits + serial-vs-overlapped cycle timings.
+def overlap_rows(smoke: bool | None = None):
+    """Per-level on/off operator splits.
 
     The hierarchy is lowered with the *measured* machine parameters
     (:func:`benchmarks.pingpong_model.measure_machine_params`), so the
     overlap-aware selection — max(T_comm, T_on) + T_off — runs on data.
     One ``dist_overlap_L{l}`` row per level records the on/off nnz split and
-    the modeled overlap efficiency; one ``dist_overlap_cycle_{V,W}`` row per
-    cycle shape times the same fused program with ``overlap`` on vs off
-    (wall clock — check_bench gates these structurally, never by magnitude).
+    the modeled overlap efficiency.
     """
     if smoke is None:
         smoke = os.environ.get("REPRO_BENCH_SMOKE", "0") == "1"
@@ -101,21 +99,16 @@ def overlap_rows(smoke: bool | None = None, cycles: int | None = None):
 
     if jax.device_count() < 2:      # nothing to overlap on one device;
         return []                   # the standalone entrypoint has 8
-    import numpy as np
-
     from benchmarks.pingpong_model import measure_machine_params
-    from repro.amg import SolveOptions, setup, solve
+    from repro.amg import setup
     from repro.amg.dist_solve import DistHierarchy
     from repro.amg.problems import laplace_3d
     from repro.core.perf_model import overlap_time
 
     n = 8 if smoke else 12
-    cycles = cycles or (3 if smoke else 10)
     n_pods, lanes = _mesh_shape(jax.device_count())
     params = measure_machine_params(n_pods=n_pods, lanes=lanes)
-    A = laplace_3d(n)
-    h = setup(A, solver="rs", max_coarse=30)   # ≥3 levels so W revisits
-    b = A.matvec(np.ones(A.nrows))
+    h = setup(laplace_3d(n), solver="rs", max_coarse=30)
     dh = DistHierarchy.build(h, n_pods, lanes, params=params)
     out = []
     for l, dl in enumerate(dh.levels):
@@ -129,26 +122,6 @@ def overlap_rows(smoke: bool | None = None, cycles: int | None = None):
             f"eff_modeled={oo['eff_modeled']:.4f};"
             f"strategy={dl.strategies.get('spmv_A', '?')};"
             f"machine={params.name}"))
-
-    def timed(opts):
-        solve(h, b, maxiter=1, tol=0.0, opts=opts, backend="dist", dist=dh)
-        t0 = time.perf_counter()
-        solve(h, b, maxiter=cycles, tol=0.0, opts=opts, backend="dist",
-              dist=dh)
-        return (time.perf_counter() - t0) / cycles * 1e6
-
-    for cycle in ("V", "W"):
-        opts = SolveOptions(cycle=cycle)
-        dh.overlap = True
-        t_overlap = timed(opts)
-        dh.overlap = False
-        t_serial = timed(opts)
-        dh.overlap = True
-        out.append((
-            f"dist_overlap_cycle_{cycle}", t_overlap,
-            f"serial_us={t_serial:.2f};overlap_us={t_overlap:.2f};"
-            f"speedup={t_serial / max(t_overlap, 1e-9):.3f};"
-            f"mesh={n_pods}x{lanes};n={A.nrows};cycles={cycles}"))
     return out
 
 
@@ -427,8 +400,8 @@ def serving_rows(smoke: bool | None = None):
     (wall-clock derived solves/s stays ungated); ``kernel=`` records which
     local kernel served the row — ``host_csr``, the fine level's layout
     (``ell``/``bcsr``) for single-request dist rows, or the native
-    multi-RHS SpMM label (``ell_spmm``/``bcsr_spmm``, ``ell_vmap`` when the
-    legacy vmap trace is forced) for coalesced batches."""
+    multi-RHS SpMM label (``ell_spmm``/``bcsr_spmm``) for coalesced
+    batches."""
     if smoke is None:
         smoke = os.environ.get("REPRO_BENCH_SMOKE", "0") == "1"
     import jax
@@ -463,8 +436,6 @@ def serving_rows(smoke: bool | None = None):
             fine = dh.kernel_table()[0]["kernel"]      # 'ell' | 'bcsr'
             if not multi:
                 return fine
-            if not dh.native_spmm:
-                return "ell_vmap"
             return f"{fine}_spmm" if fine == "bcsr" else "ell_spmm"
 
         def measure(tag, reqs, one_per_drain):

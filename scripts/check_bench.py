@@ -36,10 +36,6 @@ noise).  What is gated, per row matched by name:
   ``on_nnz + off_nnz == local_nnz`` (the split must partition the local
   block exactly), finite non-negative counts and a finite modeled overlap
   efficiency.
-* ``dist_overlap_cycle_*`` rows — serial vs overlapped timings of the same
-  fused cycle: ``serial_us``/``overlap_us`` must be finite and positive and
-  ``speedup`` recorded and finite.  The magnitudes are wall-clock derived
-  and deliberately NOT gated.
 * ``streaming_*`` rows (the drift sweep through one streaming session) —
   must be present whenever the run contains the dist-solve cycle rows,
   with the value-only refresh cheaper than the full re-setup it avoids
@@ -156,20 +152,6 @@ def check_overlap_level(name: str, vals: dict[str, float]) -> list[str]:
     if eff is None or not math.isfinite(eff):
         out.append(f"{name}: eff_modeled={eff} is not a finite overlap "
                    f"efficiency")
-    return out
-
-
-def check_overlap_cycle(name: str, vals: dict[str, float]) -> list[str]:
-    """Structural checks for one serial-vs-overlapped cycle timing row:
-    finite positive timings, speedup recorded.  Magnitudes stay ungated."""
-    out = []
-    for key in ("serial_us", "overlap_us"):
-        v = vals.get(key)
-        if v is None or not (math.isfinite(v) and v > 0):
-            out.append(f"{name}: {key}={v} is not a finite positive timing")
-    sp = vals.get("speedup")
-    if sp is None or not math.isfinite(sp):
-        out.append(f"{name}: speedup={sp} missing or non-finite")
     return out
 
 
@@ -340,9 +322,6 @@ def compare(base: dict, new: dict, max_ratio: float) -> list[str]:
         if name.startswith("dist_overlap_L"):
             compared += 1
             problems.extend(check_overlap_level(name, nvals))
-        elif name.startswith("dist_overlap_cycle_"):
-            compared += 1
-            problems.extend(check_overlap_cycle(name, nvals))
         elif name.startswith("streaming_"):
             compared += 1
             problems.extend(check_streaming(name, nvals))
@@ -356,9 +335,6 @@ def compare(base: dict, new: dict, max_ratio: float) -> list[str]:
         if not any(n.startswith("dist_overlap_L") for n in new):
             problems.append("dist_overlap_L*: per-level on/off split rows "
                             "missing from a dist-solve run")
-        if not any(n.startswith("dist_overlap_cycle_") for n in new):
-            problems.append("dist_overlap_cycle_*: serial-vs-overlap cycle "
-                            "rows missing from a dist-solve run")
         if not any(n.startswith("streaming_") for n in new):
             problems.append("streaming_*: drift-sweep rows missing from a "
                             "dist-solve run")

@@ -186,10 +186,6 @@ class AMGConfig:
     strategy: str = "auto"               # "auto" | "standard" | "nap2" | "nap3"
     machine: str = "tpu_v5e"             # repro.core.MACHINES name
     dtype: str = "float32"
-    reduce_strategy: str = "nap3"        # norms/dots: "nap3" | "flat"
-    # halo-exchange/compute overlap in every distributed apply; False keeps
-    # the serial fused form (the parity oracle)
-    overlap: bool = True
     # streaming sessions: when does an A + ΔA update escalate from a
     # value-only refresh to a full node-aware re-setup
     refresh: RefreshPolicy = dataclasses.field(default_factory=RefreshPolicy)
@@ -284,8 +280,7 @@ class AMGConfig:
                  "bfloat16": jnp.bfloat16}[self.dtype]
         return dict(n_pods=self.n_pods, lanes=self.lanes,
                     params=MACHINES[self.machine], strategy=self.strategy,
-                    dtype=dtype, reduce_strategy=self.reduce_strategy,
-                    overlap=self.overlap)
+                    dtype=dtype)
 
 
 def matrix_fingerprint(A: CSR) -> str:

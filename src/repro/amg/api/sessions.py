@@ -863,14 +863,14 @@ class AMGSolver:
         DistHierarchy.  Two cache tiers mirror the host path's setup/lower
         split: the partitioned blocks are keyed by the knobs the setup loop
         depends on (setup kwargs + mesh + strategy + machine), the lowered
-        DistHierarchy additionally by the pure lowering knobs — so configs
-        differing only in dtype/kernel/reduce knobs re-lower but never
-        re-run the setup loop, and solve-knob-only changes share both."""
+        DistHierarchy additionally by the lowering's one knob, dtype — so
+        configs differing only in dtype re-lower but never re-run the setup
+        loop, and solve-knob-only changes share both."""
         c = self.config
         base = (fp, tuple(sorted(c.setup_kwargs().items())),
                 c.n_pods, c.lanes, c.strategy, c.machine)
         pkey = base + ("dist_partitioned",)
-        skey = base + ("dist_lowered", c.dtype, c.reduce_strategy, c.overlap)
+        skey = base + ("dist_lowered", c.dtype)
         dh = self.setup_store.get(skey)
         if dh is None:
             cached = self.setup_store.get(pkey)

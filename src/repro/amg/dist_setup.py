@@ -572,7 +572,7 @@ def dist_setup(A: CSR, n_pods: int = 1, lanes: int = 1, *,
                seed: int = 42, params: MachineParams = TPU_V5E,
                strategy: str = "auto",
                strategies: tuple[str, ...] = SETUP_STRATEGIES,
-               dtype=None, mesh=None, reduce_strategy: str = "nap3"):
+               dtype=None, mesh=None):
     """Partitioned setup → :class:`~repro.amg.dist_solve.DistHierarchy`.
 
     The whole pipeline from the partitioned fine-grid A to the lowered,
@@ -592,4 +592,4 @@ def dist_setup(A: CSR, n_pods: int = 1, lanes: int = 1, *,
     return DistHierarchy.from_partitioned(
         plevels, n_pods, lanes, setup_records=records, params=params,
         strategy=strategy, dtype=jnp.float32 if dtype is None else dtype,
-        mesh=mesh, reduce_strategy=reduce_strategy)
+        mesh=mesh)

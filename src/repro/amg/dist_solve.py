@@ -174,8 +174,7 @@ class DistHierarchy:
     """
 
     def __init__(self, h: Hierarchy | None, n_pods: int, lanes: int,
-                 levels: list[DistLevel], mesh, dtype,
-                 reduce_strategy: str):
+                 levels: list[DistLevel], mesh, dtype):
         # ``h`` is None when the hierarchy was born partitioned
         # (repro.amg.dist_setup): no host Hierarchy ever existed.
         self.h = h
@@ -184,17 +183,6 @@ class DistHierarchy:
         self.levels = levels
         self.mesh = mesh
         self.dtype = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
-        self.reduce_strategy = reduce_strategy
-        # multi-RHS routing: True traces the ``*_m`` programs directly on
-        # [local, k] operands (native SpMM — one pass over each operator's
-        # nonzeros and ONE halo exchange serve all k columns); False keeps
-        # the legacy jax.vmap-over-columns trace, retained as the parity
-        # oracle the native path is tested against
-        self.native_spmm = True
-        # halo-exchange/compute overlap: True (default) traces every apply
-        # as exchange‖A_on·x then +A_off·halo; False keeps the fused serial
-        # form (halo_exchange → A·[x|halo]) as the parity oracle
-        self.overlap = True
         # program key (traced-knob subset of opts) -> (programs dict,
         # run arrays); see :meth:`programs`
         self._programs: dict[tuple, tuple] = {}
@@ -216,23 +204,18 @@ class DistHierarchy:
               params: MachineParams = TPU_V5E,
               strategy: str = "auto",
               strategies: tuple[str, ...] = SOLVE_STRATEGIES,
-              dtype=jnp.float32, mesh=None,
-              reduce_strategy: str = "nap3",
-              overlap: bool = True) -> "DistHierarchy":
+              dtype=jnp.float32, mesh=None) -> "DistHierarchy":
         """Lower ``h`` onto the mesh, selecting each operator's strategy.
 
         ``strategy="auto"`` picks per level and per operator from the
         performance models; any explicit strategy name forces it everywhere.
-        ``overlap=False`` keeps the serial fused applies (parity oracle).
         """
         if mesh is None:
             mesh = jax.make_mesh((n_pods, lanes), DEV_AXES)
         levels = cls._lower_levels(h.levels, n_pods, lanes, params=params,
                                    strategy=strategy, strategies=strategies,
                                    dtype=dtype)
-        self = cls(h, n_pods, lanes, levels, mesh, dtype, reduce_strategy)
-        self.overlap = bool(overlap)
-        return self
+        return cls(h, n_pods, lanes, levels, mesh, dtype)
 
     @classmethod
     def from_partitioned(cls, plevels, n_pods: int, lanes: int, *,
@@ -240,9 +223,7 @@ class DistHierarchy:
                          params: MachineParams = TPU_V5E,
                          strategy: str = "auto",
                          strategies: tuple[str, ...] = SOLVE_STRATEGIES,
-                         dtype=jnp.float32, mesh=None,
-                         reduce_strategy: str = "nap3",
-                         overlap: bool = True) -> "DistHierarchy":
+                         dtype=jnp.float32, mesh=None) -> "DistHierarchy":
         """Lower levels that are **already partitioned** (born on the mesh).
 
         ``plevels`` mirror :class:`~repro.amg.hierarchy.Level` but each
@@ -260,8 +241,7 @@ class DistHierarchy:
         for rec in setup_records or ():
             levels[rec.level].strategies[rec.op] = rec.strategy
             levels[rec.level].modeled[rec.op] = dict(rec.modeled)
-        self = cls(None, n_pods, lanes, levels, mesh, dtype, reduce_strategy)
-        self.overlap = bool(overlap)
+        self = cls(None, n_pods, lanes, levels, mesh, dtype)
         self.setup_records = list(setup_records or ())
         return self
 
@@ -540,15 +520,8 @@ class DistHierarchy:
             a["cinv"] = dl.coarse_inv.astype(self.dtype)
         return a
 
-    def _spmv(self, op: DistOperator, arrs: dict, x):
-        return op.apply(arrs, x, overlap=self.overlap)
-
     def _psum(self, part):
-        if self.reduce_strategy == "flat":
-            # scalar all-reduce: flat is the REDUCE_SIGNATURES["flat"]
-            # baseline the hierarchical strategy is measured against
-            return jax.lax.psum(part, DEV_AXES)  # comm-audit: allow flat-psum
-        return hier_psum(part, *DEV_AXES, strategy=self.reduce_strategy)
+        return hier_psum(part, *DEV_AXES, strategy="nap3")
 
     def _pdot(self, a, b, axis=None):
         """Global dot, replicated; ``axis=0`` gives the per-column dots
@@ -571,7 +544,7 @@ class DistHierarchy:
             dinv = dinv[:, None]
         if opts.smoother == "jacobi":
             for _ in range(sweeps):
-                x = x + opts.omega * dinv * (b - self._spmv(dl.A, aA, x))
+                x = x + opts.omega * dinv * (b - dl.A.apply(aA, x))
             return x
         if opts.smoother in ("block_jacobi", "hybrid_gs"):
             # x += w · M⁻¹ (b − A x): the halo'd residual carries every
@@ -579,22 +552,22 @@ class DistHierarchy:
             minv = arrs["minv"]
             w = opts.omega if opts.smoother == "block_jacobi" else 1.0
             for _ in range(sweeps):
-                x = x + w * (minv @ (b - self._spmv(dl.A, aA, x)))
+                x = x + w * (minv @ (b - dl.A.apply(aA, x)))
             return x
         if opts.smoother == "hybrid_gs_sym":
             # forward (D+L)⁻¹ then backward (D+U)⁻¹ half-sweep, each with a
             # freshly halo'd residual — 2 SpMVs/sweep, symmetric smoother
             minv, minv_u = arrs["minv"], arrs["minv_u"]
             for _ in range(sweeps):
-                x = x + (minv @ (b - self._spmv(dl.A, aA, x)))
-                x = x + (minv_u @ (b - self._spmv(dl.A, aA, x)))
+                x = x + (minv @ (b - dl.A.apply(aA, x)))
+                x = x + (minv_u @ (b - dl.A.apply(aA, x)))
             return x
         # Chebyshev via the recurrence shared with the host backend, the
         # matvec swapped for the level's distributed SpMV
         degree = opts.cheby_degree * sweeps
         theta, delta, sigma = chebyshev_coeffs(dl.rho)
         return chebyshev_recurrence(
-            lambda v: self._spmv(dl.A, aA, v), dinv, x, b, degree,
+            lambda v: dl.A.apply(aA, v), dinv, x, b, degree,
             theta, delta, sigma)
 
     def _cycle_dev(self, arrs, b, x, opts, level: int = 0,
@@ -615,16 +588,16 @@ class DistHierarchy:
                 x = jnp.zeros_like(b)
             x = self._relax(dl, a, x, b, opts, opts.presweeps)
         with jax.named_scope(tag + "residual"):
-            r = b - self._spmv(dl.A, a["A"], x)
+            r = b - dl.A.apply(a["A"], x)
         with jax.named_scope(tag + "restrict"):
-            rc = self._spmv(dl.R, a["R"], r)
+            rc = dl.R.apply(a["R"], r)
         ec = None
         # the coarse-grid solve(s) stay outside this level's phase scopes,
         # so an op carries exactly one level's name
         for child in CYCLE_CHILDREN[shape]:
             ec = self._cycle_dev(arrs, rc, ec, opts, level + 1, shape=child)
         with jax.named_scope(tag + "interp"):
-            x = x + self._spmv(dl.P, a["P"], ec)
+            x = x + dl.P.apply(a["P"], ec)
         with jax.named_scope(tag + "postsmooth"):
             x = self._relax(dl, a, x, b, opts, opts.postsweeps)
         return x
@@ -676,12 +649,10 @@ class DistHierarchy:
         smoother are baked in at trace time — and ``arrs`` the matching
         per-level device arrays to pass them (:meth:`run_arrays`).
         Single-RHS programs take [local] vectors; the ``*_m`` variants take
-        [local, k] multi-RHS blocks.  With :attr:`native_spmm` (the
-        default) the cycle traces directly on the [local, k] operands —
-        every SpMV is a native SpMM reading each operator's nonzeros once
-        for all k columns and exchanging ONE fused halo buffer; with it
-        off the legacy jax.vmap-over-columns trace is kept as the parity
-        oracle.  Either way, norms/dots come back as replicated [k]
+        [local, k] multi-RHS blocks: the cycle traces directly on the
+        [local, k] operands, so every SpMV is a native SpMM reading each
+        operator's nonzeros once for all k columns and exchanging ONE
+        fused halo buffer, and norms/dots come back as replicated [k]
         vectors.
 
         The cache key covers only the knobs the traced program reads —
@@ -689,8 +660,7 @@ class DistHierarchy:
         non-block smoothers) never force a bitwise-identical re-compile.
         """
         key = (opts.cycle, opts.smoother, opts.presweeps, opts.postsweeps,
-               opts.omega, opts.cheby_degree, self._smoother_arrs_key(opts),
-               self.native_spmm, self.overlap)
+               opts.omega, opts.cheby_degree, self._smoother_arrs_key(opts))
         if key in self._programs:
             return self._programs[key]
         run_arrs = self.run_arrays(opts)
@@ -705,34 +675,12 @@ class DistHierarchy:
             return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                                          out_specs=out_specs, check_vma=False))
 
-        def spmv0(arrs, x):
-            return self._spmv(self.levels[0].A, arrs[0]["A"], x)
+        def spmv0(arrs, x):                         # [local(, k)]
+            return self.levels[0].A.apply(arrs[0]["A"], x)
 
-        def spmv0_m(arrs, x):                       # [local, k] → [local, k]
-            if self.native_spmm:
-                # native SpMM: one pass over A's nonzeros (and one fused
-                # halo exchange) serves all k columns
-                return spmv0(arrs, x)
-            return jax.vmap(lambda v: spmv0(arrs, v), in_axes=1,
-                            out_axes=1)(x)
-
-        def vcycle_m(arrs, b, x):                   # batched V-cycle
-            if self.native_spmm:
-                # the whole cycle traces on [local, k] operands: every
-                # SpMV/restrict/interpolate is a native SpMM, the dense
-                # smoother factors and coarse solve are plain matmuls
-                return self._cycle_dev(arrs, b, x, opts)
-            if x is None:
-                return jax.vmap(
-                    lambda bc: self._cycle_dev(arrs, bc, None, opts),
-                    in_axes=1, out_axes=1)(b)
-            return jax.vmap(
-                lambda bc, xc: self._cycle_dev(arrs, bc, xc, opts),
-                in_axes=1, out_axes=1)(b, x)
-
-        def residual0(arrs, x, b, apply=spmv0):     # b − A·x on level 0
+        def residual0(arrs, x, b):                  # b − A·x on level 0
             with jax.named_scope("L0.residual"):
-                return b - apply(arrs, x)
+                return b - spmv0(arrs, x)
 
         def resid_norm_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
@@ -740,7 +688,7 @@ class DistHierarchy:
 
         def resid_norm_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            return self._pnorm(residual0(arrs, x, b, spmv0_m), axis=0)
+            return self._pnorm(residual0(arrs, x, b), axis=0)
 
         def cycle_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
@@ -749,9 +697,8 @@ class DistHierarchy:
 
         def cycle_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            x = vcycle_m(arrs, b, x)
-            return x[None], self._pnorm(residual0(arrs, x, b, spmv0_m),
-                                        axis=0)
+            x = self._cycle_dev(arrs, b, x, opts)
+            return x[None], self._pnorm(residual0(arrs, x, b), axis=0)
 
         def vcycle_body(b, arrs):
             b, arrs = b[0], squeeze(arrs)
@@ -759,7 +706,7 @@ class DistHierarchy:
 
         def vcycle_m_body(b, arrs):
             b, arrs = b[0], squeeze(arrs)
-            return vcycle_m(arrs, b, None)[None]
+            return self._cycle_dev(arrs, b, None, opts)[None]
 
         def pcg_init_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
@@ -770,8 +717,8 @@ class DistHierarchy:
 
         def pcg_init_m_body(x, b, arrs):
             x, b, arrs = x[0], b[0], squeeze(arrs)
-            r = residual0(arrs, x, b, spmv0_m)
-            z = vcycle_m(arrs, r, None)
+            r = residual0(arrs, x, b)
+            z = self._cycle_dev(arrs, r, None, opts)
             rz = self._pdot(r, z, axis=0)
             return r[None], z[None], rz, self._pnorm(r, axis=0)
 
@@ -796,7 +743,7 @@ class DistHierarchy:
             x, r, p = x[0], r[0], p[0]              # [local, k]; rz [k]
             arrs = squeeze(arrs)
             with jax.named_scope("L0.Ap"):
-                Ap = spmv0_m(arrs, p)
+                Ap = spmv0(arrs, p)
             den = self._pdot(p, Ap, axis=0)
             with jax.named_scope("pcg.update"):
                 # columns that already converged exactly (rz = pAp = 0,
@@ -806,7 +753,7 @@ class DistHierarchy:
                 x = x + alpha * p
                 r = r - alpha * Ap
             rnorm = self._pnorm(r, axis=0)
-            z = vcycle_m(arrs, r, None)
+            z = self._cycle_dev(arrs, r, None, opts)
             rz_new = self._pdot(r, z, axis=0)
             with jax.named_scope("pcg.update"):
                 p = z + (rz_new / jnp.where(rz == 0, 1.0, rz)) * p
@@ -845,10 +792,9 @@ class DistHierarchy:
         return getattr(self.levels[level], op).expected_signature
 
     def trace_apply(self, level: int, op: str = "A", *,
-                    overlap: bool | None = None, k: int | None = None):
+                    k: int | None = None):
         """ClosedJaxpr of one shard_mapped apply of ``levels[level].<op>``
         (``k`` adds a trailing multi-RHS axis)."""
-        overlap = self.overlap if overlap is None else overlap
         dop = getattr(self.levels[level], op)
         arrs = self._arrs[level][op]
         dev = self._dev_spec
@@ -856,7 +802,7 @@ class DistHierarchy:
         def body(x, a):
             x = x[0]
             a = jax.tree_util.tree_map(lambda v: v[0], a)
-            return dop.apply(a, x, overlap=overlap)[None]
+            return dop.apply(a, x)[None]
 
         fn = jax.shard_map(body, mesh=self.mesh, in_specs=(dev, dev),
                            out_specs=dev, check_vma=False)
@@ -927,7 +873,7 @@ class DistHierarchy:
             total += self._cycle_collectives(opts)
         if base in ("resid_norm", "cycle", "pcg_init", "pcg_step"):
             add(halo_signature(self.levels[0].A.plan))   # top-level residual
-        add(reduce_signature(self.reduce_strategy),
+        add(reduce_signature("nap3"),
             {"resid_norm": 1, "cycle": 1, "vcycle": 0,
              "pcg_init": 2, "pcg_step": 3}[base])
         return {p: c for p, c in total.items() if c}
@@ -942,7 +888,7 @@ class DistHierarchy:
 # dicts that spell a default explicitly hit the same entry
 _BUILD_DEFAULTS = dict(params=TPU_V5E, strategy="auto",
                        strategies=SOLVE_STRATEGIES, dtype=jnp.float32,
-                       mesh=None, reduce_strategy="nap3", overlap=True)
+                       mesh=None)
 DIST_CACHE_SIZE = 8
 
 

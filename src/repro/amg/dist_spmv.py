@@ -139,14 +139,15 @@ class DistOperator:
     row_part: Partition          # layout of y (output)
     col_part: Partition          # layout of x (input)
     rows_local: int              # padded local row count per device
+    # host only: the fused block the on/off split below is derived from
+    # (at build and on every value refresh); never placed on the device
     ell_cols: np.ndarray         # [D, rows_local, K] int32 into [local|halo], -1 pad
     ell_vals: np.ndarray         # [D, rows_local, K]
     send_idx: np.ndarray         # per-device slices of the plan arrays
     recv_sel: np.ndarray
     pool_sel: np.ndarray         # zeros placeholder when plan.pool_sel is None
     # on/off split of the same block: A_on holds the halo-free columns (local
-    # ids), A_off the halo columns rebased to halo-buffer ids.  The fused
-    # arrays above stay authoritative for the serial parity oracle.
+    # ids), A_off the halo columns rebased to halo-buffer ids
     on_cols: np.ndarray | None = None    # [D, rows_local, K_on] int32, -1 pad
     on_vals: np.ndarray | None = None
     off_cols: np.ndarray | None = None   # [D, rows_local, K_off] into halo
@@ -157,12 +158,10 @@ class DistOperator:
     off_rows: np.ndarray | None = None   # [D, R_off] int32, sorted
     off_ccols: np.ndarray | None = None  # [D, R_off, K_off]
     off_cvals: np.ndarray | None = None
-    # optional BCSR lowering (see lower_bcsr): dense bs×bs blocks contracted
-    # per block slot instead of one gather per nonzero
-    bcsr_bcols: np.ndarray | None = None   # [D, mb, Kb] int32, -1 pad
-    bcsr_bvals: np.ndarray | None = None   # [D, mb, Kb, bs, bs]
-    bcsr_on_bcols: np.ndarray | None = None  # on-part lowering (A_off stays ELL)
-    bcsr_on_bvals: np.ndarray | None = None
+    # optional BCSR lowering of the on-part (see lower_bcsr): dense bs×bs
+    # blocks contracted per block slot instead of one gather per nonzero
+    bcsr_on_bcols: np.ndarray | None = None  # [D, mb, Kb] int32, -1 pad
+    bcsr_on_bvals: np.ndarray | None = None  # [D, mb, Kb, bs, bs]
     block_size: int = 0                    # 0 = ELL layout
     # optional DIA lowering of the on-part (see lower_dia): one value row
     # per offset col − row, read as shifted slices of x
@@ -183,7 +182,7 @@ class DistOperator:
     def local_kernel(self) -> str:
         """Layout label for reporting: 'bcsr' or 'dia' once lowered, else
         'ell'."""
-        if self.bcsr_bcols is not None:
+        if self.bcsr_on_bcols is not None:
             return "bcsr"
         return "dia" if self.dia_vals is not None else "ell"
 
@@ -221,8 +220,7 @@ class DistOperator:
 
     def device_arrays(self) -> dict[str, np.ndarray]:
         """The sharded inputs the shard_map body needs for one matvec."""
-        arrs = {"cols": self.ell_cols, "vals": self.ell_vals,
-                "send": self.send_idx, "recv": self.recv_sel,
+        arrs = {"send": self.send_idx, "recv": self.recv_sel,
                 "psel": self.pool_sel,
                 "off_cols": self.off_cols, "off_vals": self.off_vals}
         if self.off_rows is not None:
@@ -230,58 +228,47 @@ class DistOperator:
                         off_vals=self.off_cvals)
         if self.dia_vals is not None:
             arrs["dia"] = self.dia_vals
+        elif self.bcsr_on_bcols is not None:
+            arrs.update(on_bcols=self.bcsr_on_bcols,
+                        on_bvals=self.bcsr_on_bvals)
         else:
-            arrs["on_cols"] = self.on_cols
-            arrs["on_vals"] = self.on_vals
-        if self.bcsr_bcols is not None:
-            arrs["bcols"] = self.bcsr_bcols
-            arrs["bvals"] = self.bcsr_bvals
-            arrs["on_bcols"] = self.bcsr_on_bcols
-            arrs["on_bvals"] = self.bcsr_on_bvals
+            arrs.update(on_cols=self.on_cols, on_vals=self.on_vals)
         return arrs
 
     def lower_bcsr(self, block_size: int) -> None:
-        """Lower this operator's per-device ELL blocks to block-ELL BCSR.
+        """Lower this operator's on-part to block-ELL BCSR.
 
-        Each device's (rows_local × [local|halo]) sparse block is re-tiled
-        into dense ``bs×bs`` blocks; block-row padding never mixes devices
+        Each device's (rows_local × local) halo-free block is re-tiled into
+        dense ``bs×bs`` blocks; block-row padding never mixes devices
         because each device is lowered independently.  Once lowered,
-        :meth:`apply` routes through the block contraction
+        :meth:`apply` runs ``A_on·x`` as the block contraction
         (:func:`~repro.kernels.spmv.bcsr.bcsr_apply`) instead of the ELL
-        gather.
+        gather.  The off-part stays ELL: its rows are halo-width gathers
+        that would shred into mostly-empty bs×bs blocks.
         """
         from .csr import CSR, csr_to_bcsr
         D = self.n_devices
-
-        def lower(ell_cols, ell_vals, width):
-            per = []
-            for d in range(D):
-                cols = ell_cols[d]
-                keep = cols >= 0
-                r = np.broadcast_to(
-                    np.arange(self.rows_local, dtype=np.int64)[:, None],
-                    cols.shape)[keep]
-                per.append(csr_to_bcsr(
-                    CSR.from_coo(r, cols[keep], ell_vals[d][keep],
-                                 (self.rows_local, width)), block_size))
-            mb = per[0].bcols.shape[0] if per else 0
-            Kb = max((b.bcols.shape[1] for b in per), default=0)
-            bcols = np.full((D, mb, Kb), -1, dtype=np.int32)
-            bvals = np.zeros((D, mb, Kb, block_size, block_size),
-                             dtype=ell_vals.dtype)
-            for d, b in enumerate(per):
-                kb = b.bcols.shape[1]
-                bcols[d, :, :kb] = b.bcols
-                bvals[d, :, :kb] = b.bvals
-            return bcols, bvals
-
-        xfull_len = self.plan.local_n + self.plan.halo_len
-        self.bcsr_bcols, self.bcsr_bvals = lower(
-            self.ell_cols, self.ell_vals, xfull_len)
-        # on-part only: the off-part stays ELL — its rows are halo-width
-        # gathers that would shred into mostly-empty bs×bs blocks.
-        self.bcsr_on_bcols, self.bcsr_on_bvals = lower(
-            self.on_cols, self.on_vals, self.plan.local_n)
+        width = self.plan.local_n
+        per = []
+        for d in range(D):
+            cols = self.on_cols[d]
+            keep = cols >= 0
+            r = np.broadcast_to(
+                np.arange(self.rows_local, dtype=np.int64)[:, None],
+                cols.shape)[keep]
+            per.append(csr_to_bcsr(
+                CSR.from_coo(r, cols[keep], self.on_vals[d][keep],
+                             (self.rows_local, width)), block_size))
+        mb = per[0].bcols.shape[0] if per else 0
+        Kb = max((b.bcols.shape[1] for b in per), default=0)
+        bcols = np.full((D, mb, Kb), -1, dtype=np.int32)
+        bvals = np.zeros((D, mb, Kb, block_size, block_size),
+                         dtype=self.on_vals.dtype)
+        for d, b in enumerate(per):
+            kb = b.bcols.shape[1]
+            bcols[d, :, :kb] = b.bcols
+            bvals[d, :, :kb] = b.bvals
+        self.bcsr_on_bcols, self.bcsr_on_bvals = bcols, bvals
         self.block_size = int(block_size)
 
     def lower_dia(self, offsets: tuple[int, ...]) -> None:
@@ -290,8 +277,7 @@ class DistOperator:
         :func:`~repro.kernels.spmv.ops.select_dia`).
 
         Once lowered, the halo-free product ``A_on·x`` runs as
-        :func:`~repro.kernels.spmv.dia.dia_apply`; the off-part stays ELL
-        and the fused arrays stay for the serial parity oracle.
+        :func:`~repro.kernels.spmv.dia.dia_apply`; the off-part stays ELL.
         """
         D, R, _ = self.on_cols.shape
         nb = -(-R // LANES)
@@ -357,58 +343,40 @@ class DistOperator:
             return y[: self.rows_local]
         return ell_apply(arrs["on_cols"], arrs["on_vals"], x_loc)
 
-    def _fused_product(self, arrs, xfull):
-        """``A · [x | halo]`` in one product: the serial parity oracle."""
-        if "bcols" in arrs:
-            y = bcsr_apply(arrs["bcols"], arrs["bvals"], xfull)
-            return y[: self.rows_local]
-        return ell_apply(arrs["cols"], arrs["vals"], xfull)
-
-    def apply(self, arrs: dict[str, jnp.ndarray], x_loc: jnp.ndarray,
-              overlap: bool = True) -> jnp.ndarray:
+    def apply(self, arrs: dict[str, jnp.ndarray],
+              x_loc: jnp.ndarray) -> jnp.ndarray:
         """Inside shard_map: halo exchange + local SpMV/SpMM for this device.
 
         ``arrs`` holds this device's slices of :meth:`device_arrays` (leading
         device dim already squeezed).  ``x_loc`` may be ``[local]`` (one RHS)
         or ``[local, k]`` (multi-RHS): the halo is exchanged once with the
-        RHS axis riding along.  Routing: the block-ELL product when this
-        operator was :meth:`lower_bcsr`'d, else the ELL gather product.
+        RHS axis riding along.
 
-        ``overlap=True`` (default) traces the exchange *before* the
-        independent ``y_on = A_on·x`` product so XLA's async collectives can
-        hide the NAP message latency behind the on-process SpMV; the
-        ``A_off·halo`` correction lands after.  ``overlap=False`` keeps the
-        original fused serial form (``halo_exchange → A·[x|halo]``, ELL or
-        BCSR, never DIA) as the parity oracle.  Levels whose plan moves
-        zero entries emit no collective at all in either mode.
+        The exchange is traced *before* the independent ``y_on = A_on·x``
+        product (DIA, BCSR or ELL, as lowered), so XLA's async collectives
+        can hide the NAP message latency behind the on-process SpMV; the
+        ``A_off·halo`` correction lands after.  Levels whose plan moves
+        zero entries emit no collective at all.
 
         The ops carry the scope ``halo`` (the exchange with its packing),
-        ``local`` (``A_on·x``; the whole fused product in the serial form)
-        or ``remote`` (``A_off·halo``, over the rows that read the halo
-        where the off-part was compacted).
+        ``local`` (``A_on·x``) or ``remote`` (``A_off·halo``, over the rows
+        that read the halo where the off-part was compacted).
         """
         if self.halo_empty:
             with jax.named_scope("local"):
-                if overlap:
-                    return self._on_product(arrs, x_loc)
-                return self._fused_product(arrs, x_loc)
+                return self._on_product(arrs, x_loc)
         psel = None if self.plan.pool_sel is None else arrs["psel"]
-        # the exchange goes first either way; with overlap, `halo` is
-        # not consumed until the off-process correction, so the collective
-        # and the on-process product are dataflow-independent and free to
-        # overlap
+        # `halo` is not consumed until the off-process correction, so the
+        # collective and the on-process product are dataflow-independent
+        # and free to overlap
         with jax.named_scope("halo"):
             halo = halo_exchange(x_loc, self.plan, arrs["send"],
                                  arrs["recv"], psel)
-        if overlap:
-            with jax.named_scope("local"):
-                y = self._on_product(arrs, x_loc)
-            with jax.named_scope("remote"):
-                return remote_apply(y, arrs["off_cols"], arrs["off_vals"],
-                                    halo, arrs.get("off_rows"))
         with jax.named_scope("local"):
-            xfull = jnp.concatenate([x_loc, halo])    # one buffer for all RHS
-            return self._fused_product(arrs, xfull)
+            y = self._on_product(arrs, x_loc)
+        with jax.named_scope("remote"):
+            return remote_apply(y, arrs["off_cols"], arrs["off_vals"],
+                                halo, arrs.get("off_rows"))
 
     # ------------------------------------------------------- host-side layout
     def scatter_x(self, x: np.ndarray, dtype=None) -> np.ndarray:

@@ -57,8 +57,7 @@ def run_comm_audit(n: int, pods: int, lanes: int):
             "exchange was audited", program="dist_setup"))
 
     meta = {"n": n, "pods": pods, "lanes": lanes,
-            "levels": len(dh.levels), "jax": jax.__version__,
-            "overlap": dh.overlap, "reduce_strategy": dh.reduce_strategy}
+            "levels": len(dh.levels), "jax": jax.__version__}
     return audits, violations, setup_rows, meta
 
 

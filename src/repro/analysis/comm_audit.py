@@ -9,9 +9,9 @@ extracts each collective primitive, and cross-checks against:
   tables in :mod:`repro.core.nap_collectives`) — e.g. NAP-3 ``hier_psum``
   must lower to psum_scatter(fast) + psum(slow) + all_gather(fast), a
   ``halo_empty`` level must lower to zero collectives;
-* the overlap dataflow property — with ``overlap=True`` the halo exchange
-  must be dataflow-independent of the ``A_on`` contraction (checked by a
-  taint sweep over the jaxpr's topological equation order);
+* the overlap dataflow property — in every apply that communicates, the
+  halo exchange must be dataflow-independent of the ``A_on`` contraction
+  (checked by a taint sweep over the jaxpr's topological equation order);
 * :func:`~repro.amg.dist_solve.cycle_comm_stats`' modeled counters — a
   level/op the model says communicates must have a non-empty plan, and vice
   versa;
@@ -97,18 +97,16 @@ def audit_jaxpr(jaxpr, program: str, *,
     return audit
 
 
-def audit_apply(dh, level: int, op: str = "A",
-                overlap: bool | None = None) -> CommAudit:
+def audit_apply(dh, level: int, op: str = "A") -> CommAudit:
     """Per-operator audit: one SpMV apply of ``levels[level].<op>`` must
     lower to exactly the selected strategy's ordered halo signature (empty
-    for an empty-halo plan), and — when overlapped — keep the on-process
-    contraction dataflow-independent of the exchange."""
-    overlap = dh.overlap if overlap is None else overlap
-    jaxpr = dh.trace_apply(level, op, overlap=overlap)
+    for an empty-halo plan), and keep the on-process contraction
+    dataflow-independent of the exchange."""
+    jaxpr = dh.trace_apply(level, op)
     return audit_jaxpr(
         jaxpr, f"apply_{op}",
         expected_signature=dh.expected_apply_signature(level, op),
-        require_overlap=overlap, level=level, op=op)
+        require_overlap=True, level=level, op=op)
 
 
 def audit_program(dh, name: str, opts=None, k: int = 2,
@@ -120,7 +118,7 @@ def audit_program(dh, name: str, opts=None, k: int = 2,
     jaxpr = dh.trace_program(name, opts, k=k)
     return audit_jaxpr(jaxpr, label or name,
                        expected_counts=dh.expected_collectives(opts, name),
-                       require_overlap=dh.overlap)
+                       require_overlap=True)
 
 
 def audit_cycle_stats(dh, opts=None) -> list[AuditViolation]:

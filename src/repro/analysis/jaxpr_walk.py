@@ -118,8 +118,8 @@ def _scope_has_independent_contraction(jx) -> bool:
 
     In the overlapped apply the exchange is issued first but ``A_on · x``
     consumes only local data, so its contraction is collective-independent;
-    in the serial form ``xfull = concat([x, halo])`` taints every
-    contraction.  Equations are in topological order in a jaxpr, so one
+    an apply that first concatenated ``[x | halo]`` into one product would
+    taint every contraction.  Equations are in topological order in a jaxpr, so one
     forward sweep propagating a taint set decides it.
     """
     tainted: set = set()
@@ -135,7 +135,7 @@ def _scope_has_independent_contraction(jx) -> bool:
 
 
 def check_overlap_independence(jaxpr) -> bool:
-    """The tentpole's overlap property: in every scope that communicates,
+    """The overlap property: in every scope that communicates,
     at least one local contraction is dataflow-independent of the exchange
     (so XLA is free to run them concurrently).  Vacuously true for a
     collective-free program."""

@@ -6,8 +6,8 @@
     No raw ``jax.lax`` collective calls outside ``core/nap_collectives.py``.
     Every collective must go through the NAP wrappers so the comm auditor's
     per-strategy signatures stay exhaustive.  Documented exceptions carry an
-    inline ``# comm-audit: allow <tag>`` marker (e.g. the flat-psum dot
-    products in ``dist_solve.py``) or a module-level
+    inline ``# comm-audit: allow <tag>`` marker (e.g. the flat psum of
+    ``models/moe.py``) or a module-level
     ``# comm-audit: allow-file raw-collective`` marker (e.g.
     ``train/grad_sync.py``, itself a hierarchical-collective implementation).
 

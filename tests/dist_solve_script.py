@@ -8,9 +8,8 @@ non-standard strategy somewhere in the hierarchy, that the Pallas ELL
 kernel route agrees with the inline form, that an fp64 ``AMGSolver``
 session's batched multi-RHS dist solve matches per-column host solves to
 1e-7 relative residual on the full 2x4 mesh, and that a stencil's fine
-level lowers to DIA on a 2x2 and a 2x4 mesh and solves like the ELL
-oracle.  Prints "OK <check>" per passing check; any exception fails the
-run.
+level lowers to DIA on a 2x2 and a 2x4 mesh and solves like the host.
+Prints "OK <check>" per passing check; any exception fails the run.
 """
 import os
 
@@ -114,46 +113,36 @@ def main():
         (stV, stW)
     print("OK cycle_smoother_parity")
 
-    # native multi-RHS SpMM routing (the default) vs the legacy
-    # vmap-over-columns trace: one batched [n, 4] cycle per (cycle,
-    # smoother) pair, ≤1e-7 on the same fp64 2x4 mesh.  The heuristic must
-    # have lowered at least one level to BCSR so the block path is covered.
+    # one batched [n, 4] cycle per (cycle, smoother) pair on the same fp64
+    # 2x4 mesh: the native multi-RHS SpMM trace equals the four single-RHS
+    # cycles column for column, and the overlapped on/off-process split
+    # (exchange issued before A_on·x, then + A_off·halo) equals the host
+    # cycle of each column, both ≤1e-7.  The heuristic must have lowered at
+    # least one level to BCSR so the block path is covered.
     from repro.amg.dist_solve import dist_vcycle
+    from repro.amg.solve import host_cycle
 
-    assert dh64.native_spmm, "native SpMM routing must be the default"
     assert any(r["kernel"] == "bcsr" for r in dh64.kernel_table()), \
         dh64.kernel_table()
+    assert any(not r["halo_empty"] for r in dh64.kernel_table()), \
+        "hierarchy must actually communicate somewhere"
     Bm = np.stack([b] + [np.random.default_rng(3).standard_normal(A.nrows)
                          for _ in range(3)], axis=1)
     for cycle in CYCLES:
         for sm in SMOOTHERS:
             o = SolveOptions(cycle=cycle, smoother=sm,
                              smoother_parts=N_PODS * LANES)
-            xn = dist_vcycle(dh64, Bm, o)
-            dh64.native_spmm = False
-            xv = dist_vcycle(dh64, Bm, o)
-            dh64.native_spmm = True
-            nd = np.abs(xn - xv).max() / max(np.abs(xv).max(), 1e-30)
-            assert nd < 1e-7, (cycle, sm, nd)
-    print("OK native_spmm_parity")
-
-    # overlapped on/off-process split vs the fused serial oracle: flipping
-    # dh.overlap retraces every (cycle, smoother) pair through the split
-    # A_on·x + A_off·halo path (exchange issued before the on-product);
-    # ≤1e-7 agreement on the same fp64 2x4 mesh, multi-RHS batched trace
-    assert dh64.overlap, "overlapped halo exchange must be the default"
-    assert any(not r["halo_empty"] for r in dh64.kernel_table()), \
-        "hierarchy must actually communicate somewhere"
-    for cycle in CYCLES:
-        for sm in SMOOTHERS:
-            o = SolveOptions(cycle=cycle, smoother=sm,
-                             smoother_parts=N_PODS * LANES)
-            xo = dist_vcycle(dh64, Bm, o)
-            dh64.overlap = False
-            xs = dist_vcycle(dh64, Bm, o)
-            dh64.overlap = True
-            od = np.abs(xo - xs).max() / max(np.abs(xs).max(), 1e-30)
-            assert od < 1e-7, (cycle, sm, od)
+            xm = dist_vcycle(dh64, Bm, o)
+            xc = np.stack([dist_vcycle(dh64, Bm[:, j], o)
+                           for j in range(Bm.shape[1])], axis=1)
+            xh = np.stack([host_cycle(h3, Bm[:, j], opts=o)
+                           for j in range(Bm.shape[1])], axis=1)
+            scale = max(np.abs(xh).max(), 1e-30)
+            md = np.abs(xm - xc).max() / scale
+            assert md < 1e-7, (cycle, sm, md)
+            hd = np.abs(xm - xh).max() / scale
+            assert hd < 1e-7, (cycle, sm, hd)
+    print("OK multi_rhs_parity")
     print("OK overlap_parity")
 
     # 1-device-per-node mesh (8x1): a block-diagonal operator aligned to
@@ -207,7 +196,7 @@ def main():
     # NAP-3 hier_psum shows up in resid_norm as RS(fast)+AR(slow)+AG(fast)
     rn = next(a for a in audits if a.program == "resid_norm")
     assert all(rn.counts.get(p, 0) >= 1
-               for p in REDUCE_SIGNATURES[dh64.reduce_strategy]), rn.counts
+               for p in REDUCE_SIGNATURES["nap3"]), rn.counts
     # injected regression: silently lowering hier_psum to a flat psum must
     # be caught as a count mismatch on a freshly built hierarchy
     import repro.amg.dist_solve as _ds
@@ -281,13 +270,6 @@ def main():
     rw = bound_w.solve(b, tol=0.0, maxiter=5)
     rh = solve(h, b, tol=0.0, maxiter=5, opts=cfg_w.opts)
     assert history_diff(rh.residuals, rw.residuals) < 1e-7
-    # the overlap knob threads through the dist-setup session too: the
-    # serial-oracle config reproduces the same residual history ≤1e-7
-    import dataclasses
-
-    cfg_w_ser = dataclasses.replace(cfg_w, overlap=False)
-    rw_ser = AMGSolver(cfg_w_ser).setup(A).solve(b, tol=0.0, maxiter=5)
-    assert history_diff(rw.residuals, rw_ser.residuals) < 1e-7
     print("OK dist_setup_cycles")
 
     # fp64 AMGSolver session: a [n, 4] multi-RHS dist solve batched through
@@ -364,9 +346,8 @@ def dia_layout():
     """The 27-point stencil with three whole grid planes a device: L0's A
     lowers to DIA on 27 diagonals on a 2x2 mesh (four of the eight
     devices) and on 2x4, the Galerkin levels do not, PCG agrees with the
-    overlap=False ELL oracle and the host, forcing BCSR still lowers every
-    smoothing level to BCSR, and a value refresh equals a fresh
-    lowering."""
+    host, forcing BCSR still lowers every smoothing level to BCSR, and a
+    value refresh equals a fresh lowering."""
     from repro.amg.csr import CSR
     from repro.amg.dist_solve import DEV_AXES
     from repro.amg.hierarchy import refresh_values
@@ -384,12 +365,8 @@ def dia_layout():
         assert not rows[0]["halo_empty"]
         assert all(r["kernel"] != "dia" for r in rows[1:]), rows
         got = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=dh)
-        oracle = DistHierarchy.build(h, pods, lanes, mesh=mesh,
-                                     overlap=False)
-        want = pcg(h, b, tol=1e-6, maxiter=30, backend="dist", dist=oracle)
-        assert got.converged and got.iterations == want.iterations
+        assert got.converged and got.iterations == ref.iterations
         assert history_diff(ref.residuals, got.residuals) < TOL
-        assert history_diff(want.residuals, got.residuals) < TOL
 
     import repro.amg.dist_solve as ds
     pick = ds.select_dist_kernel

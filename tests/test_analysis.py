@@ -136,7 +136,7 @@ def test_injected_empty_halo_collective_detected(dh11, monkeypatch):
 
 
 def test_overlap_independence_taint_sweep():
-    """The dataflow check behind ``overlap=True``: a contraction feeding
+    """The dataflow check behind the overlapped apply: a contraction feeding
     off the collective's output is serialized; one reading only local data
     is overlappable."""
     jax = pytest.importorskip("jax")

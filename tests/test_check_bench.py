@@ -129,24 +129,6 @@ def test_overlap_level_rows_gate_split_exactness(tmp_path):
     assert run(tmp_path, [good], nan_eff) == 1
 
 
-def test_overlap_cycle_rows_gate_structure_not_magnitude(tmp_path):
-    """dist_overlap_cycle_*: timings finite+positive, speedup recorded;
-    the speedup magnitude itself may move freely."""
-    base = [row("dist_overlap_cycle_V",
-                "serial_us=2879.68;overlap_us=2408.04;speedup=1.196;"
-                "mesh=2x4;n=512")]
-    slower = [row("dist_overlap_cycle_V",
-                  "serial_us=100.0;overlap_us=900.0;speedup=0.111;"
-                  "mesh=2x4;n=512")]
-    assert run(tmp_path, base, slower) == 0     # magnitude ungated
-    no_speedup = [row("dist_overlap_cycle_V",
-                      "serial_us=100.0;overlap_us=90.0;mesh=2x4;n=512")]
-    assert run(tmp_path, base, no_speedup) == 1
-    bad_t = [row("dist_overlap_cycle_V",
-                 "serial_us=inf;overlap_us=90.0;speedup=1.0;mesh=2x4")]
-    assert run(tmp_path, base, bad_t) == 1
-
-
 STREAMING_DERIVED = ("n=512;mesh=2x4;steps=4;solves=5;refreshes=3;"
                      "resetups=1;cached=1;max_iters=7;iters=7:7:7:7:7;"
                      "triggers=drift:3,regression:1;refresh_us=16000.0;"
@@ -166,18 +148,16 @@ def test_overlap_rows_required_with_cycle_sweep(tmp_path):
     cyc = row("dist_cycle_V_jacobi", "iters=7;conv=0.17;inter_msgs=10")
     ovl = row("dist_overlap_L0",
               "on_nnz=1;off_nnz=1;local_nnz=2;eff_modeled=0.0")
-    ovc = row("dist_overlap_cycle_V",
-              "serial_us=10.0;overlap_us=9.0;speedup=1.1")
     stm = row("streaming_refresh", STREAMING_DERIVED)
     aud = row("comm_audit_V_jacobi", COMM_AUDIT_DERIVED)
     aus = row("comm_audit_setup_L0_spgemm_AP", COMM_AUDIT_SETUP_DERIVED)
     assert run(tmp_path, [cyc], [cyc]) == 1              # all missing
-    assert run(tmp_path, [cyc], [cyc, ovl]) == 1         # cycle row missing
-    assert run(tmp_path, [cyc], [cyc, ovl, ovc]) == 1    # streaming missing
-    assert run(tmp_path, [cyc], [cyc, ovl, ovc, stm]) == 1   # audit missing
+    assert run(tmp_path, [cyc], [cyc, stm, aud, aus]) == 1   # split missing
+    assert run(tmp_path, [cyc], [cyc, ovl]) == 1         # streaming missing
+    assert run(tmp_path, [cyc], [cyc, ovl, stm]) == 1    # audit missing
     assert run(tmp_path, [cyc],
-               [cyc, ovl, ovc, stm, aud]) == 1       # setup audit missing
-    assert run(tmp_path, [cyc], [cyc, ovl, ovc, stm, aud, aus]) == 0
+               [cyc, ovl, stm, aud]) == 1            # setup audit missing
+    assert run(tmp_path, [cyc], [cyc, ovl, stm, aud, aus]) == 0
 
 
 def test_comm_audit_rows_gate_model_agreement(tmp_path):

@@ -2,9 +2,9 @@
 
 Everything here runs on the host or a 1×1 mesh — the split itself is pure
 numpy lowering, and the overlap-aware cost model is arithmetic.  The
-multi-device end-to-end parity (overlap=True vs the serial oracle across
-all 15 cycle×smoother pairs) and the 1-device-per-node empty-halo
-no-collective check run in the 8-device subprocess
+multi-device end-to-end parity (the overlapped apply against the host
+cycle across all 15 cycle×smoother pairs) and the 1-device-per-node
+empty-halo no-collective check run in the 8-device subprocess
 (tests/dist_solve_script.py, "OK overlap_parity" / "OK empty_halo").
 """
 import numpy as np
@@ -145,6 +145,67 @@ def test_operator_with_halo_in_every_row_keeps_full_rows():
     assert op.remote_rows == op.rows_local
     arrs = op.device_arrays()
     assert "off_rows" not in arrs and arrs["off_cols"] is op.off_cols
+
+
+SHARED_KEYS = {"send", "recv", "psel", "off_cols", "off_vals"}
+
+
+@pytest.mark.parametrize("strategy", ["standard", "nap2", "nap3"])
+def test_device_arrays_hold_no_fused_copy(strategy):
+    """The device gets what the overlapped apply reads and nothing more:
+    the exchange plan, the off-part and one form of the on-part — the ELL
+    split, or once lowered to BCSR only its blocks, which still hold
+    exactly A_on.  The fused block stays on the host."""
+    jnp = pytest.importorskip("jax.numpy")
+    from repro.kernels.spmv.bcsr import bcsr_apply
+    A, _ = _random_csr(seed=19, fill=0.0)
+    op = build_dist_operator(A, N_PODS, LANES, strategy, dtype=np.float64)
+    assert op.off_rows is not None
+    shared = SHARED_KEYS | {"off_rows"}
+    assert set(op.device_arrays()) == shared | {"on_cols", "on_vals"}
+    op.lower_bcsr(4)
+    assert op.local_kernel == "bcsr"
+    arrs = op.device_arrays()
+    assert set(arrs) == shared | {"on_bcols", "on_bvals"}
+    x = np.random.default_rng(3).normal(size=(op.plan.local_n, 2))
+    for d in range(op.n_devices):
+        keep = op.on_cols[d] >= 0
+        want = np.where(keep[..., None],
+                        op.on_vals[d][..., None]
+                        * x[np.maximum(op.on_cols[d], 0)], 0.0).sum(axis=1)
+        got = np.asarray(bcsr_apply(
+            jnp.asarray(arrs["on_bcols"][d]),
+            jnp.asarray(arrs["on_bvals"][d], jnp.float32),
+            jnp.asarray(x, jnp.float32)))
+        np.testing.assert_allclose(got[: op.rows_local], want,
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("cycle", ["V", "W", "F"])
+def test_multi_rhs_vcycle_equals_columns_1x1(cycle):
+    """One [n, 4] cycle on a 1×1 mesh in float64 equals the four
+    single-RHS cycles column for column and the host cycle of each
+    column: the multi-RHS programs run the same apply on [local, k]."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+    from repro.amg import SolveOptions, setup
+    from repro.amg.dist_solve import DistHierarchy, dist_vcycle
+    from repro.amg.problems import laplace_3d
+    from repro.amg.solve import host_cycle
+    A = laplace_3d(8)
+    h = setup(A, solver="rs", max_coarse=30)
+    assert h.n_levels >= 3                       # so W and F differ from V
+    B = np.random.default_rng(5).normal(size=(A.nrows, 4))
+    o = SolveOptions(cycle=cycle)
+    with jax.enable_x64(True):
+        dh = DistHierarchy.build(h, 1, 1, dtype=jnp.float64)
+        assert dh.dtype == np.float64
+        xm = dist_vcycle(dh, B, o)
+        xc = np.stack([dist_vcycle(dh, B[:, j], o) for j in range(4)], 1)
+    xh = np.stack([host_cycle(h, B[:, j], opts=o) for j in range(4)], 1)
+    scale = np.abs(xh).max()
+    assert np.abs(xm - xc).max() / scale < 1e-12
+    assert np.abs(xm - xh).max() / scale < 1e-12
 
 
 def test_onoff_nnz_partitions_local_nnz():
